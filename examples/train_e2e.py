@@ -39,13 +39,10 @@ def main():
 
     argv = ["--arch", "repro-100m", "--shape", "train_4k",
             "--steps", str(args.steps), "--batch", str(args.batch),
+            "--seq-len", str(args.seq_len),
             "--comm-mode", "explicit", "--compression", args.compression,
             "--ckpt-dir", args.ckpt_dir, "--ckpt-every", "10",
             "--log-every", "1"]
-    # shrink seq len for CPU by overriding the shape via smoke=False + batch:
-    from repro.configs import INPUT_SHAPES, InputShape
-    INPUT_SHAPES["train_4k"] = InputShape("train_4k", args.seq_len,
-                                          args.batch, "train")
     result = train_mod.main(argv)
     assert result["loss_decreased"], "loss must decrease over the run"
     print(f"[e2e] OK — loss {result['first_loss']:.3f} -> "

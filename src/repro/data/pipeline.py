@@ -13,7 +13,6 @@ import queue as queue_lib
 from typing import Any, Dict, Iterator, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import InputShape, ModelConfig
@@ -53,18 +52,34 @@ class SyntheticLM:
             step += 1
 
 
+class _Raised:
+    """Queue entry carrying an exception out of the prefetch thread."""
+
+    def __init__(self, exc: BaseException):
+        self.exc = exc
+
+
 class Prefetcher:
-    """Host-side prefetch: overlaps next-batch generation with the step."""
+    """Host-side prefetch: overlaps next-batch generation with the step.
+
+    An exception raised by the source iterator is re-raised by
+    ``__next__``, and an exhausted source ends the iteration, so a failing
+    or finished pipeline never leaves the consumer blocked.
+    """
 
     def __init__(self, it: Iterator, depth: int = 2):
         self.q: queue_lib.Queue = queue_lib.Queue(maxsize=depth)
         self._stop = threading.Event()
 
         def worker():
-            for item in it:
-                if self._stop.is_set():
-                    return
-                self.q.put(item)
+            try:
+                for item in it:
+                    if self._stop.is_set():
+                        return
+                    self.q.put(item)
+                self.q.put(_Raised(StopIteration()))
+            except Exception as e:  # handed to the consumer thread
+                self.q.put(_Raised(e))
 
         self.t = threading.Thread(target=worker, daemon=True)
         self.t.start()
@@ -73,7 +88,11 @@ class Prefetcher:
         return self
 
     def __next__(self):
-        return self.q.get()
+        item = self.q.get()
+        if isinstance(item, _Raised):
+            self.q.put(item)         # every later call raises it too
+            raise item.exc
+        return item
 
     def close(self):
         self._stop.set()
@@ -84,7 +103,7 @@ class Prefetcher:
             pass
 
 
-def device_put_batch(batch: Dict[str, np.ndarray], shardings: Any):
-    """Place a host batch on the mesh with the given shardings."""
-    return {k: jax.device_put(v, shardings[k]) if k in shardings
-            else jnp.asarray(v) for k, v in batch.items()}
+def device_put_batch(batch: Dict[str, np.ndarray], sharding: Any):
+    """Place a host batch on the mesh, every array split by ``sharding``
+    (the leading, batch dimension over the data axis)."""
+    return jax.device_put(batch, sharding)

@@ -1,9 +1,10 @@
 """Public wrappers over the Pallas compression kernels.
 
 Handles 1-D <-> tiled-2-D layout, padding to tile multiples, and backend
-dispatch: on TPU the kernels run compiled; everywhere else (this CPU
-container) they run with ``interpret=True``, which executes the kernel body
-in Python — bit-identical semantics, validated against ``ref.py``.
+dispatch: on TPU the kernels run compiled; on the CPU backend (tests) they
+run with ``interpret=True``, which executes the kernel body in Python —
+bit-identical semantics, validated against ``ref.py``.  Any other backend
+is an error, never a silent interpret-mode run.
 """
 from __future__ import annotations
 
@@ -24,7 +25,12 @@ _PAD_UNIT = BLOCK * _ROW
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas codec kernels run compiled on TPU or interpreted on "
+            f"CPU; the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 def _to_rows(x: jnp.ndarray) -> Tuple[jnp.ndarray, int]:

@@ -1,55 +1,96 @@
 """Training launcher.
 
-Runs data-parallel (+ tensor-parallel) training of any assigned architecture
-with the paper's communication phase as a configurable feature:
+Runs data-parallel training of any registered architecture on a ``data``
+mesh over every visible device, with the paper's communication phase as a
+configurable feature:
 
-- ``--comm-mode auto``      gradient averaging by XLA SPMD (pjit baseline)
-- ``--comm-mode explicit``  bucketed hierarchical grad-sync (repro.parallel.
-                            grad_sync) with optional compression — the
-                            paper-faithful Horovod-style communication phase
+- ``--comm-mode auto``      gradient averaging by XLA SPMD
+- ``--comm-mode explicit``  each device's loss and gradients on its batch
+                            shard inside a ``jax.shard_map``, averaged by
+                            the bucketed, optionally compressed exchange of
+                            ``repro.parallel.grad_sync`` — the step's only
+                            gradient reduction
 
-and the paper's *measurement methodology* built in: per-step wall time, a
-single-device baseline throughput T, and the resulting scaling factor
-T_n / (n * T) (paper Eq. 1) printed at the end.
+Parameters and optimizer state are replicated, every batch is split on
+``data``, and ``train_step`` is compiled once, before the loop: the compiled
+step refuses inputs placed any other way instead of recompiling.  A run
+reports compile time and count, median step time, tokens/s, peak device
+memory, and the compiled step's collectives and Pallas custom calls.
 
-Examples (CPU container):
-  PYTHONPATH=src python -m repro.launch.train --arch stablelm-3b --smoke \
-      --steps 20 --comm-mode explicit --compression int8
+Examples (CPU, interpret-mode Pallas kernels):
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.train \
+      --arch whisper-base --smoke --steps 5 --comm-mode explicit \
+      --compression int8
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
 import time
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-from repro.configs import CommConfig, INPUT_SHAPES, InputShape, get_config
-from repro.data.pipeline import SyntheticLM, Prefetcher
+from repro.configs import CommConfig, INPUT_SHAPES, get_config
+from repro.data.pipeline import SyntheticLM, Prefetcher, device_put_batch
 from repro.models.registry import get_model
 from repro.optim.optimizers import get_optimizer
 from repro.optim.schedule import clip_by_global_norm, get_schedule
-from repro.parallel import sharding as shd
-from repro.parallel.grad_sync import sync_grads
+from repro.parallel.grad_sync import make_plan, sync_grads
+from repro.utils import hlo
+
+REPO_ROOT = Path(__file__).resolve().parents[3]
 
 
-def build_mesh():
-    n = len(jax.devices())
-    # widest data axis that divides the device count; model gets the rest
-    data = n
-    model = 1
-    return jax.make_mesh((data, model), ("data", "model"))
+def enable_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at a fixed directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
+    sets nothing.  Otherwise the cache goes to ``<checkout>/.jax_cache``:
+    never a temp name, pid or time, since a directory that moves never hits.
+    Takes effect only before the process's first compile.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def build_mesh(devices=None):
+    """1-D ``data`` mesh over ``devices`` (default: every visible device).
+    Auto axes: XLA SPMD partitions the jitted step, ``shard_map`` takes the
+    axis over manually in explicit mode."""
+    devices = jax.devices() if devices is None else list(devices)
+    return jax.make_mesh((len(devices),), ("data",),
+                         axis_types=(AxisType.Auto,), devices=devices)
 
 
 def make_train_step(api, opt, mesh, comm: CommConfig, lr_fn,
                     clip_norm: float = 0.0):
+    value_and_grad = jax.value_and_grad(api.loss_fn, has_aux=True)
+    loss_and_grads = value_and_grad
+    if comm.mode == "explicit":
+        def local(params, batch):
+            (loss, metrics), grads = value_and_grad(params, batch)
+            grads = sync_grads(grads, comm, ("data",))
+            return jax.lax.pmean((loss, metrics), "data"), grads
+
+        # check_vma=False: gradients of the replicated params must stay
+        # per-device (no implicit psum) until sync_grads averages them, and
+        # its all-gather + local reduction leaves them replicated in value,
+        # which the varying-axes typing cannot see.
+        loss_and_grads = jax.shard_map(local, mesh=mesh,
+                                       in_specs=(P(), P("data")),
+                                       out_specs=P(), check_vma=False)
+
     def train_step(params, opt_state, batch):
-        (loss, metrics), grads = jax.value_and_grad(
-            api.loss_fn, has_aux=True)(params, batch)
-        if comm.mode == "explicit":
-            grads = sync_grads(grads, mesh, comm, batch_axes=("data",))
+        (loss, metrics), grads = loss_and_grads(params, batch)
         gnorm = jnp.zeros(())
         if clip_norm > 0:
             grads, gnorm = clip_by_global_norm(grads, clip_norm)
@@ -58,6 +99,17 @@ def make_train_step(api, opt, mesh, comm: CommConfig, lr_fn,
         return new_p, new_o, {"loss": loss, "grad_norm": gnorm, "lr": lr,
                               **metrics}
     return train_step
+
+
+def jit_train_step(train_step, mesh):
+    """``train_step`` jitted with its placement fixed: params and optimizer
+    state replicated, the batch split on ``data``.  Returns the jitted step
+    and the (replicated, batch) shardings."""
+    repl = NamedSharding(mesh, P())
+    split = NamedSharding(mesh, P("data"))
+    step = jax.jit(train_step, in_shardings=(repl, repl, split),
+                   out_shardings=repl, donate_argnums=(0, 1))
+    return step, repl, split
 
 
 def comm_from_args(args) -> CommConfig:
@@ -82,7 +134,6 @@ def dryrun(args) -> dict:
     check the scheduler wiring end-to-end — CLI flag -> CommConfig ->
     BucketPlan.comm_plan -> bucket order — without touching the data
     pipeline or jit."""
-    from repro.parallel.grad_sync import make_plan
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
@@ -99,6 +150,25 @@ def dryrun(args) -> dict:
             "n_buckets": plan.n_buckets, "bucket_order": list(order)}
 
 
+class _CompileCounter:
+    """Counts backend compiles of one jitted function by name."""
+
+    def __init__(self, fun_name: str):
+        self.fun_name, self.count = fun_name, 0
+
+    def __call__(self, event, duration, **kw):
+        if (event == "/jax/core/compile/backend_compile_duration"
+                and kw.get("fun_name") == self.fun_name):
+            self.count += 1
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(self)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self)
+
+
 def run(args) -> dict:
     if args.dryrun:
         return dryrun(args)
@@ -107,61 +177,81 @@ def run(args) -> dict:
         cfg = cfg.smoke()
     shape = INPUT_SHAPES[args.shape].smoke() if args.smoke else INPUT_SHAPES[args.shape]
     if args.batch:
-        shape = InputShape(shape.name, shape.seq_len, args.batch, shape.kind)
+        shape = dataclasses.replace(shape, global_batch=args.batch)
+    if args.seq_len:
+        shape = dataclasses.replace(shape, seq_len=args.seq_len)
 
     comm = comm_from_args(args)
     mesh = build_mesh()
     api = get_model(cfg)
     opt = get_optimizer(args.optimizer)
+    lr_fn = get_schedule(args.schedule, args.lr, args.warmup, args.steps)
+    step_fn, repl, split = jit_train_step(
+        make_train_step(api, opt, mesh, comm, lr_fn, clip_norm=args.clip_norm),
+        mesh)
 
-    params = api.init(jax.random.key(args.seed))
-    opt_state = opt.init(params)
+    params = jax.device_put(api.init(jax.random.key(args.seed)), repl)
+    opt_state = jax.device_put(opt.init(params), repl)
     n_params = sum(int(p.size) for p in jax.tree_util.tree_leaves(params))
+    n_buckets = make_plan(params, comm.fusion_buffer_mb)[0].n_buckets
     print(f"[train] {cfg.name} | {n_params/1e6:.1f}M params | "
-          f"mesh {dict(zip(mesh.axis_names, mesh.devices.shape))} | "
-          f"comm={comm.mode}/{comm.compression}")
+          f"mesh {dict(mesh.shape)} | comm={comm.mode}/{comm.compression} | "
+          f"batch {shape.global_batch} x seq {shape.seq_len}")
 
     data = SyntheticLM(cfg, shape, seed=args.seed)
-    it = Prefetcher(iter(data), depth=2)
-
-    lr_fn = get_schedule(args.schedule, args.lr, args.warmup, args.steps)
-    step_fn = jax.jit(make_train_step(api, opt, mesh, comm, lr_fn,
-                                      clip_norm=args.clip_norm),
-                      donate_argnums=(0, 1))
-    with mesh:
-        losses, times = [], []
-        t_compile = None
-        for step in range(args.steps):
-            batch = {k: jnp.asarray(v) for k, v in next(it).items()}
+    it = Prefetcher((device_put_batch(b, split) for b in data), depth=2)
+    try:
+        with _CompileCounter("jit(train_step)") as compiles:
+            batch = next(it)
             t0 = time.perf_counter()
-            params, opt_state, metrics = step_fn(params, opt_state, batch)
-            jax.block_until_ready(metrics["loss"])
-            dt = time.perf_counter() - t0
-            if step == 0:
-                t_compile = dt
-            else:
-                times.append(dt)
-            losses.append(float(metrics["loss"]))
-            if step % args.log_every == 0:
-                print(f"  step {step:4d} loss {losses[-1]:.4f} "
-                      f"({dt*1e3:.0f} ms)")
-            if args.ckpt_dir and step and step % args.ckpt_every == 0:
-                from repro.checkpoint.store import save
-                save(args.ckpt_dir, {"params": params, "opt": opt_state}, step)
-    it.close()
+            compiled = step_fn.lower(params, opt_state, batch).compile()
+            t_compile = time.perf_counter() - t0
+            losses, times = [], []
+            for step in range(args.steps):
+                if step:
+                    batch = next(it)
+                t0 = time.perf_counter()
+                params, opt_state, metrics = compiled(params, opt_state, batch)
+                jax.block_until_ready(metrics["loss"])
+                dt = time.perf_counter() - t0
+                # step 0 pays one-time costs (first transfers, allocation)
+                if step:
+                    times.append(dt)
+                losses.append(float(metrics["loss"]))
+                if step % args.log_every == 0:
+                    print(f"  step {step:4d} loss {losses[-1]:.4f} "
+                          f"({dt*1e3:.1f} ms)")
+                if args.ckpt_dir and step and step % args.ckpt_every == 0:
+                    from repro.checkpoint.store import save
+                    save(args.ckpt_dir, {"params": params, "opt": opt_state},
+                         step)
+    finally:
+        it.close()
 
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    stats = jax.devices()[0].memory_stats() or {}
     tokens_per_step = shape.global_batch * shape.seq_len
     t_step = float(np.median(times)) if times else float("nan")
     result = {
-        "arch": cfg.name, "steps": args.steps,
-        "first_loss": losses[0], "last_loss": losses[-1],
+        "arch": cfg.name, "steps": args.steps, "devices": mesh.size,
+        "global_batch": shape.global_batch, "seq_len": shape.seq_len,
+        "losses": losses, "first_loss": losses[0], "last_loss": losses[-1],
         "median_step_s": t_step, "compile_s": t_compile,
+        "compiles": compiles.count,
         "tokens_per_s": tokens_per_step / t_step if times else 0.0,
         "loss_decreased": losses[-1] < losses[0],
+        "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+        "temp_bytes": mem.temp_size_in_bytes if mem else None,
+        "argument_bytes": mem.argument_size_in_bytes if mem else None,
+        "tpu_custom_calls": text.count('custom_call_target="tpu_custom_call"'),
+        "collectives": hlo.collective_ops(text),
+        "n_buckets": n_buckets,
     }
     print(f"[train] done: loss {losses[0]:.4f} -> {losses[-1]:.4f}, "
           f"{result['tokens_per_s']:.0f} tok/s "
-          f"(median {t_step*1e3:.0f} ms/step)")
+          f"(median {t_step*1e3:.1f} ms/step, compile {t_compile:.1f} s, "
+          f"{compiles.count} compile(s) of train_step)")
     return result
 
 
@@ -172,7 +262,10 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config + tiny shape (CPU-friendly)")
     ap.add_argument("--steps", type=int, default=20)
-    ap.add_argument("--batch", type=int, default=0)
+    ap.add_argument("--batch", type=int, default=0,
+                    help="global batch (default: the shape's)")
+    ap.add_argument("--seq-len", type=int, default=0,
+                    help="sequence length (default: the shape's)")
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--schedule", default="cosine", choices=["cosine", "constant"])
     ap.add_argument("--warmup", type=int, default=5)
@@ -197,6 +290,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=100)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     return run(args)
 
 

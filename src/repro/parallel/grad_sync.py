@@ -21,22 +21,20 @@ this module implements:
   compression semantics: sums are computed on dequantized values, so
   compression error does not accumulate across hops).
 
-Everything runs under ``shard_map`` with explicit ``jax.lax`` collectives;
-``sync_grads`` is the one entry point (used by ``launch/train.py`` when
-``CommConfig.mode == "explicit"``; ``mode == "auto"`` leaves gradient
-averaging to XLA SPMD via pjit, which is the measured baseline the
-roofline tables report).
+Everything runs inside ``jax.shard_map`` with explicit ``jax.lax``
+collectives.  ``sync_grads`` is the one entry point: ``launch/train.py``
+calls it inside the same map that computes each device's loss and
+gradients when ``CommConfig.mode == "explicit"``, so the bucketed exchange
+is the step's only gradient reduction.  ``mode == "auto"`` leaves gradient
+averaging to XLA SPMD, the measured baseline.
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Callable, List, Sequence, Tuple
+from typing import Any, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import CommConfig
 from repro.kernels import ops as kops
@@ -132,13 +130,10 @@ def _allreduce_mean(x: jnp.ndarray, axes) -> jnp.ndarray:
     return jax.lax.pmean(x, axes)
 
 
-def _hierarchical_mean(x: jnp.ndarray, ici_axis: str, dcn_axis: str | None,
-                       nd: int, n_dcn: int) -> jnp.ndarray:
-    """In-pod reduce-scatter -> cross-pod all-reduce -> in-pod all-gather.
-
-    ``nd`` / ``n_dcn`` are the static mesh sizes of the two axes (jax.lax
-    has no axis_size query on this version; the caller knows the mesh).
-    """
+def _hierarchical_mean(x: jnp.ndarray, ici_axis: str,
+                       dcn_axis: str | None) -> jnp.ndarray:
+    """In-pod reduce-scatter -> cross-pod all-reduce -> in-pod all-gather."""
+    nd = jax.lax.axis_size(ici_axis)
     pad = (-x.shape[0]) % nd
     if pad:
         x = jnp.concatenate([x, jnp.zeros((pad,), x.dtype)])
@@ -150,14 +145,14 @@ def _hierarchical_mean(x: jnp.ndarray, ici_axis: str, dcn_axis: str | None,
     full = full.reshape(-1)
     if pad:
         full = full[:-pad]
-    n_total = nd * (n_dcn if dcn_axis else 1)
+    n_total = nd * (jax.lax.axis_size(dcn_axis) if dcn_axis else 1)
     return full / n_total
 
 
-def _compressed_mean(x: jnp.ndarray, comm: CommConfig, axes,
-                     n_total: int) -> jnp.ndarray:
+def _compressed_mean(x: jnp.ndarray, comm: CommConfig, axes) -> jnp.ndarray:
     """Horovod-compression semantics: all-gather compressed payloads, then
     one fused dequantize+reduce locally (Pallas ``fused_add``)."""
+    n_total = jax.lax.axis_size(axes)
     if comm.compression == "fp16":
         g = jax.lax.all_gather(x.astype(jnp.bfloat16), axes, axis=0,
                                tiled=False)
@@ -181,44 +176,48 @@ def _compressed_mean(x: jnp.ndarray, comm: CommConfig, axes,
     raise ValueError(comm.compression)
 
 
-def _sync_bucket(x: jnp.ndarray, comm: CommConfig, axes: Tuple[str, ...],
-                 axis_sizes: Tuple[int, ...]) -> jnp.ndarray:
+def _sync_bucket(x: jnp.ndarray, comm: CommConfig,
+                 axes: Tuple[str, ...]) -> jnp.ndarray:
     if comm.compression != "none":
-        n_total = 1
-        for s in axis_sizes:
-            n_total *= s
-        return _compressed_mean(x, comm, axes, n_total)
+        return _compressed_mean(x, comm, axes)
     if comm.hierarchical and len(axes) == 2:
         # axes = (pod, data): ICI inside the pod (data), DCN across (pod)
-        return _hierarchical_mean(x, ici_axis=axes[1], dcn_axis=axes[0],
-                                  nd=axis_sizes[1], n_dcn=axis_sizes[0])
+        return _hierarchical_mean(x, ici_axis=axes[1], dcn_axis=axes[0])
     if comm.hierarchical:
-        return _hierarchical_mean(x, ici_axis=axes[0], dcn_axis=None,
-                                  nd=axis_sizes[0], n_dcn=1)
+        return _hierarchical_mean(x, ici_axis=axes[0], dcn_axis=None)
     return _allreduce_mean(x, axes)
+
+
+def exchange_collectives(comm: CommConfig, n_buckets: int) -> int:
+    """Payload collectives ``sync_grads`` issues for ``n_buckets`` buckets
+    over one mesh axis: what a compiled explicit step must hold, and
+    nothing more.  Kinds are not fixed (a TPU compile lowers the
+    reduce-scatter, and the int8 scales' all-gather, as all-reduces), so
+    this is a count."""
+    if comm.compression in ("int8", "ternary"):
+        return 2 * n_buckets                # payload + scales all-gathers
+    if comm.compression != "none" or not comm.hierarchical:
+        return n_buckets                    # one all-gather / all-reduce
+    return 2 * n_buckets                    # reduce-scatter + all-gather
 
 
 # ---------------------------------------------------------------------------
 # public API
 # ---------------------------------------------------------------------------
 
-def sync_grads(grads: Any, mesh: Mesh, comm: CommConfig,
-               batch_axes: Tuple[str, ...] = ("data",)) -> Any:
-    """Average ``grads`` (replicated-param gradients) over the batch axes.
+def sync_grads(grads: Any, comm: CommConfig,
+               axes: Tuple[str, ...] = ("data",)) -> Any:
+    """Average each device's ``grads`` over the mesh axes ``axes``.
 
-    Equivalent to ``jax.tree.map(lambda g: pmean(g, batch_axes), grads)``
-    but bucketed (fusion buffers), hierarchical, and optionally compressed —
-    the paper's communication phase, implemented the way the what-if
-    analysis says it should be.
+    Call inside a ``jax.shard_map`` over ``axes`` (replicated parameters,
+    per-device gradients).  Equivalent to
+    ``jax.tree.map(lambda g: pmean(g, axes), grads)`` but bucketed (fusion
+    buffers), hierarchical, and optionally compressed — the paper's
+    communication phase, implemented the way the what-if analysis says it
+    should be.
     """
-    axes = tuple(a for a in batch_axes if a in mesh.axis_names)
-    axis_sizes = tuple(mesh.shape[a] for a in axes)
     plan, treedef = make_plan(grads, comm.fusion_buffer_mb)
-    leaves = jax.tree_util.tree_leaves(grads)
-
-    # everything is replicated w.r.t. the batch axes inside this collective;
-    # model-parallel sharding stays outside (pjit handles those dims)
-    spec = P()
+    buckets = pack(plan, jax.tree_util.tree_leaves(grads))
 
     # the comm-schedule IR orders the collectives: the same CommPlan the
     # simulator executes, so the runtime issues its buckets in the order the
@@ -228,23 +227,14 @@ def sync_grads(grads: Any, mesh: Mesh, comm: CommConfig,
     # bucket's input is barrier-chained to the previous bucket's output —
     # one collective in flight, in plan order, matching the engine's
     # serialization semantics.
-    order = plan.comm_plan(comm).bucket_order()
-
-    @functools.partial(shard_map, mesh=mesh, in_specs=spec, out_specs=spec,
-                       check_rep=False)
-    def run(*flat_leaves):
-        buckets = pack(plan, flat_leaves)
-        synced: List[jnp.ndarray] = [None] * len(buckets)  # type: ignore[list-item]
-        prev = None
-        for b in order:
-            x = buckets[b]
-            if prev is not None:
-                x, _ = jax.lax.optimization_barrier((x, prev))
-            prev = synced[b] = _sync_bucket(x, comm, axes, axis_sizes)
-        return tuple(unpack(plan, synced))
-
-    new_leaves = run(*leaves)
-    return jax.tree_util.tree_unflatten(treedef, new_leaves)
+    synced: List[jnp.ndarray] = [None] * len(buckets)  # type: ignore[list-item]
+    prev = None
+    for b in plan.comm_plan(comm).bucket_order():
+        x = buckets[b]
+        if prev is not None:
+            x, _ = jax.lax.optimization_barrier((x, prev))
+        prev = synced[b] = _sync_bucket(x, comm, axes)
+    return jax.tree_util.tree_unflatten(treedef, unpack(plan, synced))
 
 
 def grad_sync_flops_and_bytes(total_bytes: int, n_workers: int,

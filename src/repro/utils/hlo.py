@@ -305,3 +305,45 @@ def collective_counts(hlo_text: str) -> Dict[str, int]:
             continue
         out[m.group(4)] += 1
     return dict(out)
+
+
+
+def collective_ops(hlo_text: str) -> Dict[str, Dict[str, int]]:
+    """Collective instructions of a compiled module, by kind, in 3 groups.
+
+    - ``"scalar"``: every array rank 0 (metric reductions);
+    - ``"spmd"``: payload collectives whose recorded source is an op outside
+      any ``shard_map``, i.e. ones XLA's SPMD partitioner inserted (in a
+      data-parallel step: the gradient all-reduce of ``auto`` mode);
+    - ``"exchange"``: every other payload collective, i.e. those of
+      ``shard_map`` bodies, including ones the compiler rewrote and left
+      without a source (a reduce-scatter lowered to an all-reduce).
+
+    Async pairs count once.  A collective the compiler fused into several
+    consumers counts once per distinct type, not once per copy.
+    """
+    comps = parse_computations(hlo_text)
+    fused = {_callee(op.rest, "calls") for comp in comps.values()
+             for op in comp.ops if op.opcode == "fusion"}
+    out: Dict[str, Dict[str, int]] = {
+        g: defaultdict(int) for g in ("exchange", "spmd", "scalar")}
+    seen_fused = set()
+    for comp in comps.values():
+        for op in comp.ops:
+            kind = re.sub(r"-start$", "", op.opcode)
+            if kind not in COLLECTIVE_KINDS:
+                continue
+            if comp.name in fused:
+                key = (kind, re.sub(r"\{[^}]*\}", "", op.type_str))
+                if key in seen_fused:
+                    continue
+                seen_fused.add(key)
+            source = re.search(r'op_name="([^"]*)"', op.rest)
+            if not any(m.group(2) for m in _SHAPE_RE.finditer(op.type_str)):
+                group = "scalar"
+            elif source and "shard_map" not in source.group(1):
+                group = "spmd"
+            else:
+                group = "exchange"
+            out[group][kind] += 1
+    return {g: dict(v) for g, v in out.items()}

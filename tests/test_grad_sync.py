@@ -1,6 +1,6 @@
 """Bucketed grad-sync: packing plan properties (hypothesis), single-device
-semantics, and real multi-device collective semantics in a subprocess with 8
-fake XLA devices."""
+semantics, real multi-device collective semantics in a subprocess with 8
+fake XLA devices, and the data-parallel train step on 4 of them."""
 import json
 import os
 import subprocess
@@ -12,10 +12,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import CommConfig
-from repro.parallel.grad_sync import (BucketPlan, make_plan, pack, sync_grads,
-                                      unpack)
+from repro.parallel.grad_sync import (BucketPlan, exchange_collectives,
+                                      make_plan, pack, sync_grads, unpack)
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -65,11 +66,12 @@ def test_pack_unpack_roundtrip(seed, limit_kb):
 
 @pytest.mark.parametrize("compression", ["none", "fp16", "int8"])
 def test_sync_identity_on_one_device(compression):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1,), ("data",))
     grads = {"w": jnp.arange(300, dtype=jnp.float32).reshape(20, 15) / 300.0,
              "b": jnp.ones((7,), jnp.bfloat16)}
     comm = CommConfig(compression=compression, hierarchical=False)
-    out = sync_grads(grads, mesh, comm)
+    out = jax.shard_map(lambda g: sync_grads(g, comm, ("data",)), mesh=mesh,
+                        in_specs=P(), out_specs=P(), check_vma=False)(grads)
     tol = {"none": 1e-7, "fp16": 1e-2, "int8": 1e-2}[compression]
     for a, b in zip(jax.tree_util.tree_leaves(out),
                     jax.tree_util.tree_leaves(grads)):
@@ -84,7 +86,7 @@ def test_sync_identity_on_one_device(compression):
 _MULTIDEV_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import json, sys
+import functools, json, sys
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 sys.path.insert(0, "src")
@@ -111,27 +113,20 @@ for compression, hier in [("none", False), ("none", True), ("fp16", False),
     expect_b = np.mean([np.asarray(v) for v in vals_b], axis=0)
 
     comm = CommConfig(compression=compression, hierarchical=hier)
-    # grads replicated per device: shard_map sees per-device blocks; here we
-    # feed the (2,4,...)-stacked tree and read back block 0 via reshard
-    import functools
-    from jax.experimental.shard_map import shard_map
-    @functools.partial(shard_map, mesh=mesh,
+    # each device holds its own gradients: a (1, 1, ...) block of the
+    # (2, 4, ...)-stacked tree; the sync must leave every block the mean
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P("pod", "data"), P("pod", "data")),
                        out_specs=(P("pod", "data"), P("pod", "data")),
-                       check_rep=False)
+                       check_vma=False)
     def run(wb, bb):
-        g = {"w": wb[0, 0], "b": bb[0, 0]}
-        from repro.parallel.grad_sync import _sync_bucket, make_plan, pack, unpack
-        plan, tdef = make_plan(g, comm.fusion_buffer_mb)
-        buckets = pack(plan, jax.tree_util.tree_leaves(g))
-        axes = ("pod", "data")
-        synced = [_sync_bucket(x, comm, axes, (2, 4)) for x in buckets]
-        out = unpack(plan, synced)
-        return out[1][None, None], out[0][None, None]   # leaves sorted: b, w
+        out = sync_grads({"w": wb[0, 0], "b": bb[0, 0]}, comm,
+                         ("pod", "data"))
+        return out["w"][None, None], out["b"][None, None]
 
-    out_w, out_b = run(w, b)   # run returns (w, b): leaves sort as (b, w)
-    got_w = np.asarray(out_w)[0, 0]
-    got_b = np.asarray(out_b)[0, 0]
+    out_w, out_b = run(w, b)
+    got_w = np.asarray(out_w)            # every device's block
+    got_b = np.asarray(out_b)
     err_w = float(np.abs(got_w - expect_w).max())
     err_b = float(np.abs(got_b - expect_b).max())
     results[f"{compression}/{'hier' if hier else 'flat'}"] = [err_w, err_b]
@@ -153,3 +148,54 @@ def test_multidevice_mean_semantics(tmp_path):
            "int8/flat": 2e-2, "ternary/flat": 1.5}
     for k, (ew, eb) in results.items():
         assert ew <= tol[k] and eb <= tol[k], (k, ew, eb)
+
+
+# ---------------------------------------------------------------------------
+# the data-parallel train step on 4 devices (subprocess)
+# ---------------------------------------------------------------------------
+
+_TRAIN_4DEV_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json, sys
+sys.path.insert(0, "src")
+from repro.launch import train
+
+out = {}
+for mode in ("auto", "explicit"):
+    r = train.main(["--arch", "whisper-base", "--smoke", "--steps", "2",
+                    "--batch", "8", "--fusion-mb", "1", "--warmup", "0",
+                    "--lr", "1e-2", "--log-every", "100",
+                    "--comm-mode", mode])
+    out[mode] = {k: r[k] for k in ("losses", "collectives", "n_buckets",
+                                   "devices", "compiles")}
+print(json.dumps(out))
+"""
+
+
+@pytest.mark.slow
+def test_explicit_step_matches_auto_on_four_devices(tmp_path):
+    """Explicit mode's per-device loss/grad + bucketed exchange matches XLA
+    SPMD data parallelism, and is the compiled step's only gradient
+    reduction: no collective the SPMD partitioner inserted, and exactly the
+    bucket plan's exchange collectives."""
+    script = tmp_path / "train4.py"
+    script.write_text(_TRAIN_4DEV_SCRIPT)
+    env = dict(os.environ, PYTHONPATH="src")
+    proc = subprocess.run([sys.executable, str(script)], cwd=REPO, env=env,
+                          capture_output=True, text=True, timeout=560)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    auto, exp = out["auto"], out["explicit"]
+    assert auto["devices"] == exp["devices"] == 4
+    assert auto["compiles"] == exp["compiles"] == 1
+    # a step with lr 1e-2 moves the loss, so step 1 checks the update too
+    np.testing.assert_allclose(exp["losses"], auto["losses"], rtol=1e-5)
+    assert auto["collectives"]["spmd"], "auto mode: XLA reduces the grads"
+    nb = exp["n_buckets"]
+    assert nb > 1
+    assert exp["collectives"]["spmd"] == {}
+    assert exp["collectives"]["exchange"] == {"reduce-scatter": nb,
+                                              "all-gather": nb}
+    assert (sum(exp["collectives"]["exchange"].values())
+            == exchange_collectives(CommConfig(), nb))

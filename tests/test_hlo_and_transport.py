@@ -143,3 +143,42 @@ ENTRY %main (a: f32[4]) -> f32[4] {
 """
     comps = parse_computations(txt)
     assert {"body", "cond", "main"} <= set(comps)
+
+
+# ---------------------------------------------------------------------------
+# collective provenance (scalar / exchange / SPMD-inserted)
+# ---------------------------------------------------------------------------
+
+HLO_PROVENANCE = """
+HloModule jit_train_step
+
+%fused_computation.1 (param_0.1: f32[2,64]) -> f32[4,2,64] {
+  %param_0.1 = f32[2,64]{1,0} parameter(0)
+  ROOT %all-gather.1 = f32[4,2,64]{2,1,0} all-gather(%param_0.1), dimensions={0}, metadata={op_name="jit(train_step)/shard_map/all_gather"}
+}
+
+%fused_computation.2 (param_0.2: f32[2,64]) -> f32[4,2,64] {
+  %param_0.2 = f32[2,64]{1,0} parameter(0)
+  ROOT %all-gather.2 = f32[4,2,64]{2,1,0} all-gather(%param_0.2), dimensions={0}, metadata={op_name="jit(train_step)/shard_map/all_gather"}
+}
+
+ENTRY %main (p: f32[8,64]) -> f32[] {
+  %p = f32[8,64]{1,0} parameter(0)
+  %rs = f32[8,64]{1,0} all-reduce(%p), to_apply=%add
+  %f1 = f32[4,2,64]{2,1,0} fusion(%p), kind=kLoop, calls=%fused_computation.1
+  %f2 = f32[4,2,64]{2,1,0} fusion(%p), kind=kLoop, calls=%fused_computation.2
+  %g = bf16[512,64]{1,0} all-reduce-start(%p), to_apply=%add, metadata={op_name="jit(train_step)/transpose(jvp(dot_general))"}
+  %gd = bf16[512,64]{1,0} all-reduce-done(%g)
+  ROOT %loss = (f32[], f32[]) all-reduce(%p, %p), to_apply=%add, metadata={op_name="jit(train_step)/shard_map/psum"}
+}
+"""
+
+
+def test_collective_ops_groups_by_provenance():
+    from repro.utils.hlo import collective_ops
+    out = collective_ops(HLO_PROVENANCE)
+    # the source-less all-reduce is a compiler-rewritten exchange op; the
+    # all-gather fused into two consumers counts once
+    assert out["exchange"] == {"all-reduce": 1, "all-gather": 1}
+    assert out["spmd"] == {"all-reduce": 1}          # async pair, once
+    assert out["scalar"] == {"all-reduce": 1}

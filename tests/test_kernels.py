@@ -116,3 +116,15 @@ def test_fused_add_linearity(k, n, seed):
     out1 = ops.fused_add(bufs)
     np.testing.assert_allclose(np.asarray(out2), 2 * np.asarray(out1),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_interpret_mode_only_on_cpu(monkeypatch):
+    """Kernels are compiled on TPU, interpreted on the CPU backend, and
+    refused anywhere else rather than silently interpreted."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert ops._interpret() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops._interpret()

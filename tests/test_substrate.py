@@ -1,5 +1,13 @@
 """Substrate tests: data pipeline determinism, checkpoint roundtrip,
 optimizers descend, train/serve launchers run end-to-end."""
+import json
+import math
+import os
+import subprocess
+import sys
+from concurrent.futures import ThreadPoolExecutor
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,6 +18,8 @@ from repro.data.pipeline import Prefetcher, SyntheticLM
 
 # interpret-mode Pallas / full-model tests: minutes of wall clock on CPU
 pytestmark = pytest.mark.slow
+
+REPO = Path(__file__).resolve().parent.parent
 
 
 
@@ -51,6 +61,31 @@ def test_prefetcher():
     b0, b1 = next(it), next(it)
     assert not np.array_equal(b0["tokens"], b1["tokens"])
     it.close()
+
+
+def _next_within(it, seconds=10.0):
+    """``next(it)`` in a helper thread, so a hang fails instead of blocking."""
+    with ThreadPoolExecutor(1) as ex:
+        return ex.submit(next, it).result(timeout=seconds)
+
+
+def test_prefetcher_reraises_source_error():
+    def source():
+        yield 1
+        raise ValueError("bad shard")
+
+    it = Prefetcher(source(), depth=2)
+    assert _next_within(it) == 1
+    for _ in range(2):                     # and on every later call
+        with pytest.raises(ValueError, match="bad shard"):
+            _next_within(it)
+
+
+def test_prefetcher_stops_with_source():
+    it = Prefetcher(iter([1, 2, 3]), depth=2)
+    assert [_next_within(it) for _ in range(3)] == [1, 2, 3]
+    with pytest.raises(StopIteration):
+        _next_within(it)
 
 
 # ---------------------------------------------------------------------------
@@ -126,6 +161,55 @@ def test_train_explicit_comm_matches_auto():
                             "100"])
     # on one device explicit sync is a no-op: identical loss trajectories
     assert abs(r_auto["last_loss"] - r_exp["last_loss"]) < 1e-4
+
+
+def test_train_compiles_step_once():
+    """Placed inputs keep step 1's argument types equal to step 0's, so the
+    step is compiled once per run; ``--seq-len`` overrides the shape."""
+    from repro.launch import train as train_mod
+    res = train_mod.main(["--arch", "whisper-base", "--smoke", "--steps", "3",
+                          "--seq-len", "32", "--comm-mode", "explicit",
+                          "--log-every", "100"])
+    assert res["compiles"] == 1, res
+    assert res["seq_len"] == 32 and len(res["losses"]) == 3
+    assert all(math.isfinite(x) for x in res["losses"])
+
+
+_CACHE_SCRIPT = r"""
+import json, sys
+sys.path.insert(0, "src")
+import jax, jax.numpy as jnp
+from repro.launch import train
+path = train.enable_compile_cache()
+if sys.argv[1] == "compile":
+    jax.jit(lambda x: x * 2)(jnp.ones(3)).block_until_ready()
+print(json.dumps({"returned": path,
+                  "config": jax.config.jax_compilation_cache_dir}))
+"""
+
+
+@pytest.mark.parametrize("env_dir", [False, True], ids=["default", "env"])
+def test_compile_cache_dir(tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and is written to; otherwise the cache
+    is the fixed directory in the checkout."""
+    script = tmp_path / "cache.py"
+    script.write_text(_CACHE_SCRIPT)
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = "src"
+    cache = tmp_path / "cache"
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(cache)
+        env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+    proc = subprocess.run(
+        [sys.executable, str(script), "compile" if env_dir else "none"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    want = str(cache) if env_dir else str(REPO / ".jax_cache")
+    assert out["returned"] == out["config"] == want
+    if env_dir:
+        assert any(cache.iterdir()), "the compile was not cached there"
 
 
 def test_serve_generates():
