@@ -9,11 +9,13 @@ host-side prefetch.
 from __future__ import annotations
 
 import threading
+import time
 import queue as queue_lib
 from typing import Any, Dict, Iterator, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import InputShape, ModelConfig
 
@@ -52,6 +54,9 @@ class SyntheticLM:
             step += 1
 
 
+_END = object()      # what the source gives once it is exhausted
+
+
 class _Raised:
     """Queue entry carrying an exception out of the prefetch thread."""
 
@@ -65,19 +70,40 @@ class Prefetcher:
     An exception raised by the source iterator is re-raised by
     ``__next__``, and an exhausted source ends the iteration, so a failing
     or finished pipeline never leaves the consumer blocked.
+
+    Each side names its work with a profiler span (free when no profiler
+    runs) and counts it (``stats``): the producer thread ``input.produce``
+    around pulling the next item from the source (for ``device_put_batch``
+    over a generator: drawing the batch and starting its copy to the
+    device) and ``input.queue_full`` around a ``put`` that found the queue
+    full; the consumer ``input.wait`` around its ``get``.
     """
 
     def __init__(self, it: Iterator, depth: int = 2):
         self.q: queue_lib.Queue = queue_lib.Queue(maxsize=depth)
         self._stop = threading.Event()
+        self.produced, self.produce_s, self.queue_full_s = 0, 0.0, 0.0
+        self.starved, self.wait_s = 0, 0.0
 
         def worker():
             try:
-                for item in it:
-                    if self._stop.is_set():
+                src = iter(it)
+                while not self._stop.is_set():
+                    t0 = time.perf_counter()
+                    with TraceAnnotation("input.produce"):
+                        item = next(src, _END)
+                    if item is _END:
+                        self.q.put(_Raised(StopIteration()))
                         return
-                    self.q.put(item)
-                self.q.put(_Raised(StopIteration()))
+                    self.produce_s += time.perf_counter() - t0
+                    self.produced += 1
+                    try:
+                        self.q.put_nowait(item)
+                    except queue_lib.Full:
+                        t0 = time.perf_counter()
+                        with TraceAnnotation("input.queue_full"):
+                            self.q.put(item)
+                        self.queue_full_s += time.perf_counter() - t0
             except Exception as e:  # handed to the consumer thread
                 self.q.put(_Raised(e))
 
@@ -88,11 +114,26 @@ class Prefetcher:
         return self
 
     def __next__(self):
-        item = self.q.get()
+        t0 = time.perf_counter()
+        with TraceAnnotation("input.wait"):
+            try:
+                item = self.q.get_nowait()
+            except queue_lib.Empty:
+                self.starved += 1
+                item = self.q.get()
+        self.wait_s += time.perf_counter() - t0
         if isinstance(item, _Raised):
             self.q.put(item)         # every later call raises it too
             raise item.exc
         return item
+
+    def stats(self) -> Dict[str, float]:
+        """Batches produced, seconds producing them, seconds the producer
+        waited on a full queue, gets that found the queue empty, and
+        seconds the consumer waited in all."""
+        return {"produced": self.produced, "produce_s": self.produce_s,
+                "queue_full_s": self.queue_full_s, "starved": self.starved,
+                "wait_s": self.wait_s}
 
     def close(self):
         self._stop.set()

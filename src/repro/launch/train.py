@@ -14,18 +14,30 @@ configurable feature:
 Parameters and optimizer state are replicated, every batch is split on
 ``data``, and ``train_step`` is compiled once, before the loop: the compiled
 step refuses inputs placed any other way instead of recompiling.  A run
-reports compile time and count, median step time, tokens/s, peak device
-memory, and the compiled step's collectives and Pallas custom calls.
+reports compile time and count, the median step time and tokens/s (each
+step timed from asking for its batch to its loss being ready, the first
+step left out), peak device memory, the compiled step's collectives and
+Pallas custom calls, the input pipeline's counters (``Prefetcher.stats``)
+and the compiled step's instructions per layer (``hlo.layers``).
+
+The loop names its work for the JAX profiler: each step is a
+``StepTraceAnnotation("train")`` holding the spans ``train.dispatch`` and
+``train.block`` (and ``train.checkpoint``).  ``--profile-dir DIR`` traces
+steps 1-3 into ``DIR``; without it the loop starts no profiler.
 
 Examples (CPU, interpret-mode Pallas kernels):
   JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.train \
       --arch whisper-base --smoke --steps 5 --comm-mode explicit \
       --compression int8
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.train \
+      --arch whisper-base --smoke --steps 5 --profile-dir /tmp/prof
 """
 from __future__ import annotations
 
 import argparse
+import collections
 import dataclasses
+import glob
 import os
 import time
 from pathlib import Path
@@ -33,6 +45,7 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.configs import CommConfig, INPUT_SHAPES, get_config
@@ -47,13 +60,18 @@ REPO_ROOT = Path(__file__).resolve().parents[3]
 
 
 def enable_compile_cache() -> str:
-    """Point JAX's persistent compilation cache at a fixed directory.
+    """Point JAX's persistent compilation cache at a fixed directory, and
+    key its entries on the programs' metadata too.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
-    sets nothing.  Otherwise the cache goes to ``<checkout>/.jax_cache``:
+    sets no directory.  Otherwise the cache goes to ``<checkout>/.jax_cache``:
     never a temp name, pid or time, since a directory that moves never hits.
+    JAX leaves metadata out of the key by default, so a step whose named
+    scopes changed would be handed an executable compiled under the old
+    ones, and its ``op_name``s, the layers ``hlo.layers`` reads, with it.
     Takes effect only before the process's first compile.
     """
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
@@ -73,7 +91,16 @@ def build_mesh(devices=None):
 
 def make_train_step(api, opt, mesh, comm: CommConfig, lr_fn,
                     clip_norm: float = 0.0):
-    value_and_grad = jax.value_and_grad(api.loss_fn, has_aux=True)
+    """The training step, its parts under the named scopes of
+    ``hlo.SCOPES``: ``model`` around the loss (forward, and its transpose,
+    the backward), ``optimizer`` around clipping, the learning rate and the
+    update, and ``sync_grads``'s own in explicit mode.  Scopes are metadata:
+    they name the compiled step's instructions and change none."""
+    def loss_fn(params, batch):
+        with jax.named_scope(hlo.MODEL):
+            return api.loss_fn(params, batch)
+
+    value_and_grad = jax.value_and_grad(loss_fn, has_aux=True)
     loss_and_grads = value_and_grad
     if comm.mode == "explicit":
         def local(params, batch):
@@ -91,11 +118,12 @@ def make_train_step(api, opt, mesh, comm: CommConfig, lr_fn,
 
     def train_step(params, opt_state, batch):
         (loss, metrics), grads = loss_and_grads(params, batch)
-        gnorm = jnp.zeros(())
-        if clip_norm > 0:
-            grads, gnorm = clip_by_global_norm(grads, clip_norm)
-        lr = lr_fn(opt_state.count)
-        new_p, new_o = opt.update(params, opt_state, grads, lr)
+        with jax.named_scope(hlo.OPTIMIZER):
+            gnorm = jnp.zeros(())
+            if clip_norm > 0:
+                grads, gnorm = clip_by_global_norm(grads, clip_norm)
+            lr = lr_fn(opt_state.count)
+            new_p, new_o = opt.update(params, opt_state, grads, lr)
         return new_p, new_o, {"loss": loss, "grad_norm": gnorm, "lr": lr,
                               **metrics}
     return train_step
@@ -169,6 +197,20 @@ class _CompileCounter:
         jax.monitoring.unregister_event_duration_listener(self)
 
 
+# the steps that ``--profile-dir`` traces: after the first, which compiles
+# nothing but pays the first transfers
+PROFILE_STEPS = range(1, 4)
+
+
+def _stop_profile(profile_dir: str) -> str:
+    """Stop the profiler; print and return the trace file it wrote."""
+    jax.profiler.stop_trace()
+    path = max(glob.glob(f"{profile_dir}/**/*.xplane.pb", recursive=True),
+               key=os.path.getmtime)
+    print(f"[train] profile of steps {PROFILE_STEPS[0]}-{PROFILE_STEPS[-1]}: {path}")
+    return path
+
+
 def run(args) -> dict:
     if args.dryrun:
         return dryrun(args)
@@ -200,6 +242,7 @@ def run(args) -> dict:
 
     data = SyntheticLM(cfg, shape, seed=args.seed)
     it = Prefetcher((device_put_batch(b, split) for b in data), depth=2)
+    tracing, profile = False, None
     try:
         with _CompileCounter("jit(train_step)") as compiles:
             batch = next(it)
@@ -208,24 +251,36 @@ def run(args) -> dict:
             t_compile = time.perf_counter() - t0
             losses, times = [], []
             for step in range(args.steps):
-                if step:
-                    batch = next(it)
-                t0 = time.perf_counter()
-                params, opt_state, metrics = compiled(params, opt_state, batch)
-                jax.block_until_ready(metrics["loss"])
-                dt = time.perf_counter() - t0
-                # step 0 pays one-time costs (first transfers, allocation)
-                if step:
-                    times.append(dt)
-                losses.append(float(metrics["loss"]))
-                if step % args.log_every == 0:
-                    print(f"  step {step:4d} loss {losses[-1]:.4f} "
-                          f"({dt*1e3:.1f} ms)")
-                if args.ckpt_dir and step and step % args.ckpt_every == 0:
-                    from repro.checkpoint.store import save
-                    save(args.ckpt_dir, {"params": params, "opt": opt_state},
-                         step)
+                if args.profile_dir and step == PROFILE_STEPS[0]:
+                    jax.profiler.start_trace(args.profile_dir)
+                    tracing = True
+                with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                    t0 = time.perf_counter()
+                    if step:
+                        batch = next(it)
+                    with TraceAnnotation("train.dispatch"):
+                        params, opt_state, metrics = compiled(params, opt_state,
+                                                              batch)
+                    with TraceAnnotation("train.block"):
+                        jax.block_until_ready(metrics["loss"])
+                    dt = time.perf_counter() - t0
+                    # step 0 pays one-time costs (first transfers, allocation)
+                    if step:
+                        times.append(dt)
+                    losses.append(float(metrics["loss"]))
+                    if step % args.log_every == 0:
+                        print(f"  step {step:4d} loss {losses[-1]:.4f} "
+                              f"({dt*1e3:.1f} ms)")
+                    if args.ckpt_dir and step and step % args.ckpt_every == 0:
+                        from repro.checkpoint.store import save
+                        with TraceAnnotation("train.checkpoint"):
+                            save(args.ckpt_dir,
+                                 {"params": params, "opt": opt_state}, step)
+                if tracing and step == PROFILE_STEPS[-1]:
+                    tracing, profile = False, _stop_profile(args.profile_dir)
     finally:
+        if tracing:          # the run ended inside PROFILE_STEPS
+            profile = _stop_profile(args.profile_dir)
         it.close()
 
     text = compiled.as_text()
@@ -239,7 +294,7 @@ def run(args) -> dict:
         "losses": losses, "first_loss": losses[0], "last_loss": losses[-1],
         "median_step_s": t_step, "compile_s": t_compile,
         "compiles": compiles.count,
-        "tokens_per_s": tokens_per_step / t_step if times else 0.0,
+        "tokens_per_s": tokens_per_step * len(times) / sum(times) if times else 0.0,
         "loss_decreased": losses[-1] < losses[0],
         "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
         "temp_bytes": mem.temp_size_in_bytes if mem else None,
@@ -247,6 +302,9 @@ def run(args) -> dict:
         "tpu_custom_calls": text.count('custom_call_target="tpu_custom_call"'),
         "collectives": hlo.collective_ops(text),
         "n_buckets": n_buckets,
+        "input": it.stats(),
+        "layer_ops": dict(collections.Counter(hlo.layers(text).values())),
+        "profile": profile,
     }
     print(f"[train] done: loss {losses[0]:.4f} -> {losses[-1]:.4f}, "
           f"{result['tokens_per_s']:.0f} tok/s "
@@ -289,6 +347,10 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=5)
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=100)
+    ap.add_argument("--profile-dir", default="",
+                    help="trace steps 1-3 with the JAX profiler into this "
+                         "directory (device ops, the step's named scopes, "
+                         "the input.* and train.* spans)")
     args = ap.parse_args(argv)
     enable_compile_cache()
     return run(args)

@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.configs.base import CommConfig
 from repro.kernels import ops as kops
+from repro.utils import hlo
 
 
 # ---------------------------------------------------------------------------
@@ -217,7 +218,8 @@ def sync_grads(grads: Any, comm: CommConfig,
     should be.
     """
     plan, treedef = make_plan(grads, comm.fusion_buffer_mb)
-    buckets = pack(plan, jax.tree_util.tree_leaves(grads))
+    with jax.named_scope(hlo.PACK):
+        buckets = pack(plan, jax.tree_util.tree_leaves(grads))
 
     # the comm-schedule IR orders the collectives: the same CommPlan the
     # simulator executes, so the runtime issues its buckets in the order the
@@ -230,11 +232,13 @@ def sync_grads(grads: Any, comm: CommConfig,
     synced: List[jnp.ndarray] = [None] * len(buckets)  # type: ignore[list-item]
     prev = None
     for b in plan.comm_plan(comm).bucket_order():
-        x = buckets[b]
-        if prev is not None:
-            x, _ = jax.lax.optimization_barrier((x, prev))
-        prev = synced[b] = _sync_bucket(x, comm, axes)
-    return jax.tree_util.tree_unflatten(treedef, unpack(plan, synced))
+        with jax.named_scope(f"{hlo.BUCKET}{b}"):
+            x = buckets[b]
+            if prev is not None:
+                x, _ = jax.lax.optimization_barrier((x, prev))
+            prev = synced[b] = _sync_bucket(x, comm, axes)
+    with jax.named_scope(hlo.UNPACK):
+        return jax.tree_util.tree_unflatten(treedef, unpack(plan, synced))
 
 
 def grad_sync_flops_and_bytes(total_bytes: int, n_workers: int,

@@ -347,3 +347,81 @@ def collective_ops(hlo_text: str) -> Dict[str, Dict[str, int]]:
                 group = "exchange"
             out[group][kind] += 1
     return {g: dict(v) for g, v in out.items()}
+
+
+# ---------------------------------------------------------------------------
+# layers of the training step, from its named scopes
+# ---------------------------------------------------------------------------
+
+# The ``jax.named_scope`` names that ``launch/train.py`` and
+# ``parallel/grad_sync.py`` put around the parts of the training step.  A
+# bucket's scope carries its index: ``exchange.bucket3``.
+SCOPES = (MODEL, PACK, BUCKET, UNPACK, OPTIMIZER) = (
+    "model", "exchange.pack", "exchange.bucket", "exchange.unpack", "optimizer")
+# what ``layers`` maps an instruction to
+LAYERS = ("forward", "backward", PACK, BUCKET, UNPACK, OPTIMIZER, "mixed",
+          "none")
+
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+# fused instructions that compute nothing of their own: XLA shares and
+# hoists them between scopes, under names such as ``shard_map/broadcast.167``
+_PLACES_DATA = ("parameter", "constant", "broadcast", "bitcast")
+_BUCKET_RE = re.compile(re.escape(BUCKET) + r"\d+")
+
+
+def scope_layer(op_name: str) -> str:
+    """The layer an ``op_name`` records: the outermost program scope in it.
+    Under ``model``, an op that ``value_and_grad`` transposed is backward
+    (a forward recomputed inside the backward included), any other forward.
+    No program scope: ``none``."""
+    for part in op_name.split("/"):
+        name = part.rstrip(")").rsplit("(", 1)[-1]   # transpose(jvp(model)) -> model
+        if name == MODEL:
+            return "backward" if part.startswith("transpose(") else "forward"
+        if name in (PACK, UNPACK, OPTIMIZER):
+            return name
+        if _BUCKET_RE.fullmatch(name):
+            return BUCKET
+    return "none"
+
+
+def layers(text: str) -> Dict[str, str]:
+    """Instruction name -> layer (one of ``LAYERS``) for every instruction of
+    every computation of a compiled module's text, ``while`` bodies
+    included.  An instruction takes the layer of its ``op_name`` metadata;
+    a fusion takes the one layer that every instruction fused into it
+    records, ``mixed`` where they disagree, and its own where none records
+    any.  Fused instructions that only place data (``_PLACES_DATA``) do not
+    count, nor does an ``op_name`` that records no traced operation, one
+    that does not start ``jit(``: an argument's path (``params['w']``) or
+    an instruction name that XLA made up (``broadcast.182``)."""
+    comps = parse_computations(text)
+    own = {}
+    for comp in comps.values():
+        for op in comp.ops:
+            m = _OP_NAME_RE.search(op.rest)
+            own[op.name] = (scope_layer(m.group(1))
+                            if m and m.group(1).startswith("jit(") else None)
+    fused: Dict[str, set] = {}
+
+    def recorded(comp_name: str) -> set:
+        """Layers recorded inside a fused computation, nested fusions too."""
+        if comp_name not in fused:
+            fused[comp_name] = set()
+            for op in comps[comp_name].ops:
+                callee = _callee(op.rest, "calls") if op.opcode == "fusion" else None
+                if callee:
+                    fused[comp_name] |= recorded(callee)
+                elif op.opcode not in _PLACES_DATA:
+                    fused[comp_name] |= {own[op.name]} - {None}
+        return fused[comp_name]
+
+    out: Dict[str, str] = {}
+    for comp in comps.values():
+        for op in comp.ops:
+            callee = _callee(op.rest, "calls") if op.opcode == "fusion" else None
+            inside = recorded(callee) if callee else set()
+            out[op.name] = ("mixed" if len(inside) > 1 else
+                            next(iter(inside)) if inside else
+                            own[op.name] or "none")
+    return out
