@@ -2,7 +2,8 @@
 
 Compression hot-spots the paper's §3.2 varies: quantize (int8/ternary),
 topk_mask, fused_add.  Model hot-spots surfaced by the roofline analysis:
-flash_attn (online softmax), wkv (RWKV6), ssm_scan (Mamba selective scan).
+wkv (RWKV6), ssm_scan (Mamba selective scan); attention runs JAX's own
+splash-attention kernel, dispatched from ``repro.models.attention``.
 All validated in interpret mode against the oracles; model dispatch via
 ``ModelConfig.use_pallas``.
 """

@@ -1,18 +1,22 @@
-"""Attention variants: GQA with chunked online-softmax ("flash" in pure jnp),
-MLA (DeepSeek-V2 latent attention), sliding-window masking, and single-token
-decode against (optionally ring-buffer) KV caches.
+"""Attention variants: GQA with chunked online-softmax ("flash" in pure jnp,
+or JAX's Pallas TPU splash kernel under ``cfg.use_pallas``), MLA (DeepSeek-V2
+latent attention), sliding-window masking, and single-token decode against
+(optionally ring-buffer) KV caches.
 
-Memory discipline: training/prefill never materializes an (Sq, Skv) score
-matrix larger than (attn_chunk, attn_chunk) per (batch, kv-head, group).
+Memory discipline: the kernel keeps its score tiles in VMEM, forward and
+backward. The jnp path holds one (attn_chunk, attn_chunk) score tile per
+(batch, kv-head, group) when attn_chunk divides the lengths, else the whole
+(Sq, Skv) matrix.
 """
 from __future__ import annotations
 
-import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import Params, apply_rope, dense_init, split_keys
@@ -82,43 +86,55 @@ def use_pallas(cfg) -> bool:
     return jax.default_backend() == "tpu"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_pallas_cv(q, k, v, causal, n_heads, n_kv_heads):
-    """Pallas forward with the pure-jnp path's gradients (recompute in
-    backward) — the standard pattern until a bwd kernel lands."""
-    from repro.kernels.flash_attn import flash_attention_pallas
-    B, Hq, Sq, d = q.shape
-    Hkv = k.shape[1]
-    out = flash_attention_pallas(
-        q.reshape(B * Hq, Sq, d), k.reshape(B * Hkv, k.shape[2], d),
-        v.reshape(B * Hkv, v.shape[2], d), causal=causal,
-        n_heads=Hq, n_kv_heads=Hkv,
-        interpret=jax.default_backend() != "tpu")
-    return out.reshape(B, Hq, Sq, d)
+def _tile(n: int) -> int:
+    """Largest kernel block (512, 256 or 128) that divides ``n``."""
+    return next(b for b in (512, 256, 128) if n % b == 0)
 
 
-def _flash_cv_fwd(q, k, v, causal, n_heads, n_kv_heads):
-    return _flash_pallas_cv(q, k, v, causal, n_heads, n_kv_heads), (q, k, v)
+def _flash_pallas(q, k, v, causal):
+    """JAX's Pallas TPU splash-attention kernel: forward, and one fused
+    backward kernel for dq, dk and dv, with f32 softmax statistics; the
+    scores stay in VMEM both ways. Hq may be a multiple of Hkv (GQA).
 
-
-def _flash_cv_bwd(causal, n_heads, n_kv_heads, res, g):
-    q, k, v = res
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: _flash_reference(q_, k_, v_, causal), q, k, v)
-    return vjp(g)
-
-
-def _flash_reference(q, k, v, causal):
-    return flash_attention(q, k, v, causal=causal, chunk=1024,
-                           _allow_pallas=False)
-
-
-_flash_pallas_cv.defvjp(_flash_cv_fwd, _flash_cv_bwd)
+    Sq and Skv are padded up to multiples of 128 and the output sliced
+    back; the slice's gradient is zero on padded rows, so dk, dv get nothing
+    from them. A static mask keeps padded keys from every query (causal
+    calls, Sq <= Skv, need none). The kv block is the whole padded kv up to
+    2048 (the encoder's 1536: 14% less fwd+bwd time on a v5e than 512).
+    q, k and v enter the kernel sequence-minor, (d, S): with a head dim of
+    64 the (S, d) layout half-fills each 128-lane tile, and whisper-base's
+    step on a v5e took 310 ms this way against 355.
+    """
+    Hq, Sq, d = q.shape[1:]
+    Skv = k.shape[2]
+    Sqp, Skp = Sq + -Sq % 128, Skv + -Skv % 128
+    if causal:
+        mask = splash.CausalMask((Sqp, Skp))
+    elif Skp != Skv:
+        mask = splash.NumpyMask(
+            np.broadcast_to(np.arange(Skp) < Skv, (Sqp, Skp)).copy())
+    else:
+        mask = splash.FullMask((Sqp, Skp))
+    bq, bkv_compute = _tile(Sqp), _tile(Skp)
+    bkv = Skp if Skp <= 2048 else bkv_compute
+    seq_minor = splash.QKVLayout.SEQ_MINOR
+    blocks = splash.BlockSizes(
+        block_q=bq, block_kv=bkv, block_kv_compute=bkv_compute,
+        block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkv_compute,
+        use_fused_bwd_kernel=True, q_layout=seq_minor, k_layout=seq_minor,
+        v_layout=seq_minor)
+    kernel = splash.make_splash_mha(
+        splash.MultiHeadMask([mask] * Hq), block_sizes=blocks, head_shards=1,
+        q_seq_shards=1, interpret=jax.default_backend() != "tpu")
+    pad = lambda x, n: jnp.pad(x, ((0, 0), (0, 0), (0, n - x.shape[2]), (0, 0)))
+    q = (q * (1.0 / math.sqrt(d))).astype(q.dtype)   # the kernel does not scale
+    out = jax.vmap(kernel)(pad(q, Sqp), pad(k, Skp), pad(v, Skp))
+    return out[:, :, :Sq]
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     chunk: int = 1024, q_offset: int = 0,
-                    cfg=None, _allow_pallas: bool = True) -> jnp.ndarray:
+                    cfg=None) -> jnp.ndarray:
     """GQA-aware chunked attention.
 
     q: (B, Hq, Sq, d); k, v: (B, Hkv, Skv, d); Hq % Hkv == 0.
@@ -127,14 +143,14 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     never holds more than one (chunk x chunk) score tile per head-group.
 
     When ``cfg.use_pallas`` resolves true and the shape qualifies (no
-    window/offset, same qk/v dims, 128-aligned), dispatches to the Pallas
-    online-softmax kernel (repro.kernels.flash_attn).
+    window/offset, same qk/v dims), dispatches to JAX's Pallas TPU
+    splash-attention kernel, forward and backward, at any length: both
+    lengths are padded to multiples of 128 and the padded keys masked
+    (``_flash_pallas``).
     """
-    if (_allow_pallas and cfg is not None and use_pallas(cfg)
-            and window == 0 and q_offset == 0
-            and q.shape[-1] == v.shape[-1]
-            and q.shape[2] % 128 == 0 and k.shape[2] % 128 == 0):
-        return _flash_pallas_cv(q, k, v, causal, q.shape[1], k.shape[1])
+    if (cfg is not None and use_pallas(cfg) and window == 0 and q_offset == 0
+            and q.shape[-1] == v.shape[-1]):
+        return _flash_pallas(q, k, v, causal)
     B, Hq, Sq, d = q.shape
     Hkv = k.shape[1]
     G = Hq // Hkv

@@ -79,7 +79,8 @@ def encode(params: Params, frames: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray
         q = (h @ bp["attn"]["wq"]).reshape(B, S, H, hd).transpose(0, 2, 1, 3)
         k = (h @ bp["attn"]["wk"]).reshape(B, S, KV, hd).transpose(0, 2, 1, 3)
         v = (h @ bp["attn"]["wv"]).reshape(B, S, KV, hd).transpose(0, 2, 1, 3)
-        a = flash_attention(q, k, v, causal=False, chunk=cfg.attn_chunk)
+        a = flash_attention(q, k, v, causal=False, chunk=cfg.attn_chunk,
+                            cfg=cfg)
         a = a.transpose(0, 2, 1, 3).reshape(B, S, H * hd) @ bp["attn"]["wo"]
         x = x + a
         x = x + mlp(bp["mlp"], rms_norm(x, bp["ln2"]["w"], cfg.norm_eps))
@@ -109,7 +110,7 @@ def _cross_attend(bp: Params, h, xk, xv, cfg: ModelConfig):
     q = (h @ bp["wq"]).reshape(B, Sq, H, hd).transpose(0, 2, 1, 3)
     k = xk.transpose(0, 2, 1, 3)
     v = xv.transpose(0, 2, 1, 3)
-    a = flash_attention(q, k, v, causal=False, chunk=cfg.attn_chunk)
+    a = flash_attention(q, k, v, causal=False, chunk=cfg.attn_chunk, cfg=cfg)
     return a.transpose(0, 2, 1, 3).reshape(B, Sq, H * hd) @ bp["wo"]
 
 

@@ -1,5 +1,5 @@
 """Model-level Pallas kernels (WKV recurrence, flash attention) vs their
-pure-jnp oracles, swept over shapes."""
+pure-jnp or dense oracles, swept over shapes."""
 import math
 
 import jax
@@ -7,9 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.flash_attn import flash_attention_pallas
 from repro.kernels.wkv import wkv_pallas
-from repro.models.attention import flash_attention as flash_jnp
 from repro.models.rwkv import wkv_chunked
 
 # interpret-mode Pallas / full-model tests: minutes of wall clock on CPU
@@ -65,43 +63,6 @@ def test_wkv_pallas_state_chain():
                                rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("BH,Sq,hd,causal", [
-    (4, 256, 64, True), (2, 128, 64, False), (1, 512, 32, True),
-    (3, 128, 128, True),
-])
-def test_flash_pallas_matches_softmax(BH, Sq, hd, causal):
-    ks = jax.random.split(jax.random.key(BH * 31 + Sq), 3)
-    q = jax.random.normal(ks[0], (BH, Sq, hd), jnp.float32)
-    k = jax.random.normal(ks[1], (BH, Sq, hd), jnp.float32)
-    v = jax.random.normal(ks[2], (BH, Sq, hd), jnp.float32)
-    out = flash_attention_pallas(q, k, v, causal=causal, interpret=True)
-    # dense reference
-    s = jnp.einsum("bqd,bkd->bqk", q, k) / math.sqrt(hd)
-    if causal:
-        mask = jnp.tril(jnp.ones((Sq, Sq), bool))
-        s = jnp.where(mask, s, -1e30)
-    ref = jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, axis=-1), v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-3, atol=2e-3)
-
-
-def test_flash_pallas_matches_model_flash():
-    """Pallas kernel agrees with the pure-jnp chunked attention used by the
-    model stack (same semantics, different implementations)."""
-    B, H, S, hd = 2, 4, 256, 64
-    ks = jax.random.split(jax.random.key(3), 3)
-    q = jax.random.normal(ks[0], (B, H, S, hd), jnp.float32)
-    k = jax.random.normal(ks[1], (B, H, S, hd), jnp.float32)
-    v = jax.random.normal(ks[2], (B, H, S, hd), jnp.float32)
-    ref = flash_jnp(q, k, v, causal=True, chunk=128)
-    out = flash_attention_pallas(q.reshape(B * H, S, hd),
-                                 k.reshape(B * H, S, hd),
-                                 v.reshape(B * H, S, hd),
-                                 causal=True, interpret=True)
-    np.testing.assert_allclose(np.asarray(ref).reshape(B * H, S, hd),
-                               np.asarray(out), rtol=2e-3, atol=2e-3)
-
-
 @pytest.mark.parametrize("B,S,di,n,chunk,di_block", [
     (2, 256, 256, 16, 64, 128), (1, 128, 128, 8, 128, 128),
     (2, 192, 512, 16, 64, 256),
@@ -128,7 +89,7 @@ def test_ssm_scan_pallas_matches_ref(B, S, di, n, chunk, di_block):
 
 def test_pallas_dispatch_in_model():
     """cfg.use_pallas='always' routes gqa_forward through the Pallas kernel
-    (custom_vjp: kernel forward, reference backward) with matching grads."""
+    (kernel forward, kernel backward) with matching grads."""
     from repro.configs import get_config
     from repro.models import attention as a
     cfg = get_config("stablelm-3b").smoke().replace(attn_chunk=128,
@@ -148,24 +109,91 @@ def test_pallas_dispatch_in_model():
                                rtol=2e-4, atol=2e-4)
 
 
-def test_flash_pallas_gqa_index_map():
-    """GQA via kv index map equals explicit kv repetition."""
-    from repro.kernels.flash_attn import flash_attention_pallas
-    B, Hq, Hkv, S, hd = 2, 4, 2, 128, 64
-    ks = jax.random.split(jax.random.key(5), 3)
-    q = jax.random.normal(ks[0], (B * Hq, S, hd), jnp.float32)
-    k = jax.random.normal(ks[1], (B * Hkv, S, hd), jnp.float32)
-    v = jax.random.normal(ks[2], (B * Hkv, S, hd), jnp.float32)
-    out = flash_attention_pallas(q, k, v, causal=True, n_heads=Hq,
-                                 n_kv_heads=Hkv, interpret=True)
-    # reference: repeat kv heads explicitly
-    G = Hq // Hkv
-    k_rep = jnp.repeat(k.reshape(B, Hkv, S, hd), G, axis=1).reshape(B * Hq, S, hd)
-    v_rep = jnp.repeat(v.reshape(B, Hkv, S, hd), G, axis=1).reshape(B * Hq, S, hd)
-    ref = flash_attention_pallas(q, k_rep, v_rep, causal=True,
-                                 n_heads=Hq, n_kv_heads=Hq, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5,
-                               atol=1e-5)
+def _dense_attention(q, k, v, causal):
+    """f32 softmax over the whole (Sq, Skv) score matrix; GQA by repeat."""
+    g = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   precision="highest") / math.sqrt(q.shape[-1])
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -1e30)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v,
+                      precision="highest")
+
+
+@pytest.mark.parametrize("B,Hq,Hkv,Sq,Skv,causal,dtype,tol", [
+    (2, 2, 2, 200, 300, False, jnp.float32, 1e-4),   # cross-attention shape
+    (1, 2, 2, 300, 300, False, jnp.float32, 1e-4),
+    (1, 2, 2, 200, 200, True, jnp.float32, 1e-4),
+    (1, 2, 2, 256, 256, True, jnp.float32, 1e-4),    # aligned: no padding
+    (1, 4, 2, 200, 200, True, jnp.float32, 1e-4),    # GQA
+    (1, 1, 1, 100, 600, False, jnp.float32, 1e-4),   # one kv block, 5 tiles
+    (1, 1, 1, 100, 2200, False, jnp.float32, 1e-4),  # 9 kv blocks of 256
+    (1, 1, 1, 200, 300, False, jnp.bfloat16, 3e-2),
+], ids=["cross", "self", "causal", "aligned", "gqa", "kv-tiles", "kv-blocks",
+        "bf16"])
+def test_flash_kernel_padded_matches_softmax(B, Hq, Hkv, Sq, Skv, causal,
+                                             dtype, tol):
+    """The padded kernel path's output and dq, dk, dv against a dense f32
+    softmax (tolerances relative to each array's largest entry)."""
+    from repro.models.attention import _flash_pallas
+    ks = jax.random.split(jax.random.key(Sq * 7 + Skv + Hq), 4)
+    q = jax.random.normal(ks[0], (B, Hq, Sq, 64)).astype(dtype)
+    k = jax.random.normal(ks[1], (B, Hkv, Skv, 64)).astype(dtype)
+    v = jax.random.normal(ks[2], (B, Hkv, Skv, 64)).astype(dtype)
+    do = jax.random.normal(ks[3], (B, Hq, Sq, 64))
+    f32 = lambda x: x.astype(jnp.float32)
+    out, vjp = jax.vjp(lambda *a: _flash_pallas(*a, causal), q, k, v)
+    ref, vjp_ref = jax.vjp(lambda *a: _dense_attention(*a, causal),
+                           f32(q), f32(k), f32(v))
+    grads = vjp(do.astype(dtype))
+    grads_ref = vjp_ref(do)
+    for got, want in zip((out, *grads), (ref, *grads_ref)):
+        assert got.shape == want.shape and got.dtype == dtype
+        scale = float(jnp.max(jnp.abs(want)))
+        np.testing.assert_allclose(np.asarray(f32(got)), np.asarray(want),
+                                   rtol=0, atol=tol * scale)
+
+
+def test_pallas_dispatch_in_whisper():
+    """use_pallas='always' puts the kernel in whisper's encoder
+    self-attention, cross-attention and decoder self-attention, and the
+    loss and parameter gradients (remat on) match use_pallas='never'."""
+    from repro.configs import get_config
+    from repro.models import attention as a
+    from repro.models import whisper as w
+    cfg = get_config("whisper-base").smoke().replace(remat=True,
+                                                     use_pallas="never")
+    cfg_p = cfg.replace(use_pallas="always")
+    params = w.init_model(jax.random.key(0), cfg)
+    B, S = 2, 24
+    ks = jax.random.split(jax.random.key(1), 3)
+    batch = {"frames": jax.random.normal(ks[0], (B, cfg.encoder_seq,
+                                                 cfg.d_model)) * 0.3,
+             "tokens": jax.random.randint(ks[1], (B, S), 0, cfg.vocab_size),
+             "labels": jax.random.randint(ks[2], (B, S), 0, cfg.vocab_size)}
+    layer0 = jax.tree_util.tree_map(lambda x: x[0], params["dec_blocks"])
+    enc = w.encode(params, batch["frames"], cfg)
+    h = jax.random.normal(ks[0], (B, S, cfg.d_model)) * 0.3
+    xk, xv = w._cross_kv(layer0["xattn"], enc, cfg)
+    jaxprs = {
+        "encode": jax.make_jaxpr(
+            lambda f: w.encode(params, f, cfg_p))(batch["frames"]),
+        "cross": jax.make_jaxpr(
+            lambda x: w._cross_attend(layer0["xattn"], x, xk, xv, cfg_p))(h),
+        "decoder": jax.make_jaxpr(
+            lambda x: a.gqa_forward(layer0["attn"], x, cfg_p)[0])(h),
+    }
+    for name, jaxpr in jaxprs.items():
+        assert "pallas_call" in str(jaxpr), name
+    loss = jax.value_and_grad(lambda p, c: w.loss_fn(p, batch, c)[0])
+    l_ref, g_ref = loss(params, cfg)
+    l_pal, g_pal = loss(params, cfg_p)
+    np.testing.assert_allclose(float(l_pal), float(l_ref), rtol=2e-4)
+    for x, y in zip(jax.tree_util.tree_leaves(g_ref),
+                    jax.tree_util.tree_leaves(g_pal)):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                   rtol=2e-4, atol=2e-4)
 
 
 def test_pallas_dispatch_mamba():

@@ -1,9 +1,11 @@
-"""The codec kernels compile for a TPU v5e, checked without one.
+"""The codec kernels and the attention kernels compile for a TPU v5e,
+checked without one.
 
 Each Pallas kernel of the explicit gradient exchange is compiled at a 64 MiB
-fusion bucket for one chip of a described (not attached) ``v5e:2x2``
-topology, and must come out as a Mosaic ``tpu_custom_call``: what interpret
-mode on the CPU cannot show (tiling, VMEM limits, lowering).
+fusion bucket, and whisper-base's three attentions (forward and backward) at
+the benchmark's shapes, for one chip of a described (not attached)
+``v5e:2x2`` topology, and must come out as Mosaic ``tpu_custom_call``s: what
+interpret mode on the CPU cannot show (tiling, VMEM limits, lowering).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and the test workers all import
@@ -20,6 +22,7 @@ from repro.kernels.fused_add import fused_add_2d
 from repro.kernels.quantize import (BLOCK, dequantize_int8_2d,
                                     quantize_int8_2d, ternarize_2d)
 from repro.kernels.topk_mask import topk_mask_2d
+from repro.models import attention
 
 BUCKET_ROWS = 64 * 1024 * 1024 // 4 // BLOCK      # 64 MiB of f32 = (65536, 256)
 BUCKET_ELEMS = BUCKET_ROWS * BLOCK
@@ -79,3 +82,26 @@ def test_fused_add_compiles_for_v5e(one_chip, dtype):
                                    sharding=one_chip)
     text = _compiled_text(fused_add_2d, buffers)
     assert 'custom_call_target="tpu_custom_call"' in text
+
+
+@pytest.mark.parametrize("Sq,Skv,causal", [(1500, 1500, False),
+                                           (448, 1500, False),
+                                           (448, 448, True)],
+                         ids=["encoder", "cross", "decoder"])
+def test_attention_kernels_compile_for_v5e(one_chip, monkeypatch, Sq, Skv,
+                                           causal):
+    """32 x 8 heads x 64, bf16: the forward kernel and the fused backward
+    kernel, and no f32 score matrix left in the compiled gradient."""
+    # the described chip is not the default backend: compile, not interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = lambda s: jax.ShapeDtypeStruct((32, 8, s, 64), jnp.bfloat16,
+                                           sharding=one_chip)
+
+    def loss(q, k, v):
+        out = attention._flash_pallas(q, k, v, causal)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), shape(Sq),
+                          shape(Skv), shape(Skv))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert f"f32[32,8,{Sq},{Skv}]" not in text
