@@ -21,8 +21,8 @@ from repro.models import attention as attn_lib
 from repro.models import mamba as mamba_lib
 from repro.models import moe as moe_lib
 from repro.models.layers import (Params, chunked_softmax_xent, dense_init,
-                                 embed_init, init_mlp, mlp, rms_norm,
-                                 split_keys)
+                                 embed_init, embed_lookup, init_mlp, mlp,
+                                 rms_norm, split_keys)
 
 
 def block_layout(cfg: ModelConfig):
@@ -99,7 +99,7 @@ def _layer_forward(lp: Params, x, cfg: ModelConfig, mixer: str, use_moe: bool,
 
 def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
             want_cache: bool = False):
-    x = params["embed"]["w"][tokens]
+    x = embed_lookup(params["embed"]["w"], tokens)
     layout = block_layout(cfg)
 
     def body(carry, bp):
@@ -177,7 +177,7 @@ def _layer_decode(lp: Params, x, cache, cache_index, cfg: ModelConfig,
 
 def decode_step(params: Params, token: jnp.ndarray, cache, cache_index,
                 cfg: ModelConfig):
-    x = params["embed"]["w"][token]
+    x = embed_lookup(params["embed"]["w"], token)
     layout = block_layout(cfg)
 
     def body(h, inp):

@@ -24,7 +24,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import (Params, chunked_softmax_xent, dense_init,
-                                 embed_init, rms_norm, split_keys)
+                                 embed_init, embed_lookup, rms_norm,
+                                 split_keys)
 
 DECAY_LORA = 64
 
@@ -235,7 +236,7 @@ def _block(bp: Params, x, cfg: ModelConfig, state=None):
 
 def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
             want_state: bool = False, state=None):
-    x = params["embed"]["w"][tokens]
+    x = embed_lookup(params["embed"]["w"], tokens)
 
     def body(carry, inp):
         h = carry

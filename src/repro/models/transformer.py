@@ -18,8 +18,8 @@ from repro.configs.base import ModelConfig
 from repro.models import attention as attn_lib
 from repro.models import moe as moe_lib
 from repro.models.layers import (Params, chunked_softmax_xent, dense_init,
-                                 embed_init, init_mlp, mlp, rms_norm,
-                                 split_keys)
+                                 embed_init, embed_lookup, init_mlp, mlp,
+                                 rms_norm, split_keys)
 
 
 # ---------------------------------------------------------------------------
@@ -92,7 +92,7 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
             prefix_embeds: Optional[jnp.ndarray] = None, want_cache: bool = False
             ) -> Tuple[jnp.ndarray, jnp.ndarray, Any]:
     """tokens: (B, S) int32 -> (hidden (B,S,D), aux_loss scalar, caches)."""
-    x = params["embed"]["w"][tokens]
+    x = embed_lookup(params["embed"]["w"], tokens)
     if prefix_embeds is not None:
         x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
     aux_total = jnp.zeros((), jnp.float32)
@@ -234,7 +234,7 @@ def decode_step(params: Params, token: jnp.ndarray, cache: Dict[str, Any],
     """token: (B, 1) int32; cache_index: () int32 tokens already cached.
 
     Returns (logits (B, 1, V), new_cache)."""
-    x = params["embed"]["w"][token]
+    x = embed_lookup(params["embed"]["w"], token)
     new_caches = {}
 
     def run_stack(x, stack, stack_cache, n_layers, use_moe, name):

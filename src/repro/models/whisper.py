@@ -18,8 +18,8 @@ from repro.configs.base import ModelConfig
 from repro.models import attention as attn_lib
 from repro.models.attention import decode_attention, flash_attention
 from repro.models.layers import (Params, chunked_softmax_xent, dense_init,
-                                 embed_init, init_mlp, mlp, rms_norm,
-                                 split_keys)
+                                 embed_init, embed_lookup, init_mlp, mlp,
+                                 rms_norm, split_keys)
 
 
 # ---------------------------------------------------------------------------
@@ -128,7 +128,7 @@ def _dec_block(bp: Params, x, enc_out, cfg: ModelConfig, want_cache: bool):
 
 def decode_stack(params: Params, tokens: jnp.ndarray, enc_out: jnp.ndarray,
                  cfg: ModelConfig, want_cache: bool = False):
-    x = params["embed"]["w"][tokens]
+    x = embed_lookup(params["embed"]["w"], tokens)
 
     def body(h, bp):
         h, cache = _dec_block(bp, h, enc_out, cfg, want_cache)
@@ -173,7 +173,7 @@ def cache_spec(cfg: ModelConfig, batch: int, cache_len: int) -> Dict[str, Any]:
 def decode_step(params: Params, token: jnp.ndarray, cache, cache_index,
                 cfg: ModelConfig):
     """token: (B, 1); cache: stacked {k, v, xk, xv} from prefill/cache_spec."""
-    x = params["embed"]["w"][token]
+    x = embed_lookup(params["embed"]["w"], token)
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
     def body(h, inp):

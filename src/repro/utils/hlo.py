@@ -353,14 +353,16 @@ def collective_ops(hlo_text: str) -> Dict[str, Dict[str, int]]:
 # layers of the training step, from its named scopes
 # ---------------------------------------------------------------------------
 
-# The ``jax.named_scope`` names that ``launch/train.py`` and
-# ``parallel/grad_sync.py`` put around the parts of the training step.  A
-# bucket's scope carries its index: ``exchange.bucket3``.
-SCOPES = (MODEL, PACK, BUCKET, UNPACK, OPTIMIZER) = (
-    "model", "exchange.pack", "exchange.bucket", "exchange.unpack", "optimizer")
+# The ``jax.named_scope`` names that ``launch/train.py``,
+# ``parallel/grad_sync.py`` and ``models/layers.embed_lookup`` put around the
+# parts of the training step.  A bucket's scope carries its index:
+# ``exchange.bucket3``.  ``embed.grad`` lies inside ``model``'s backward.
+SCOPES = (MODEL, EMBED_GRAD, PACK, BUCKET, UNPACK, OPTIMIZER) = (
+    "model", "embed.grad", "exchange.pack", "exchange.bucket",
+    "exchange.unpack", "optimizer")
 # what ``layers`` maps an instruction to
-LAYERS = ("forward", "backward", PACK, BUCKET, UNPACK, OPTIMIZER, "mixed",
-          "none")
+LAYERS = ("forward", "backward", EMBED_GRAD, PACK, BUCKET, UNPACK, OPTIMIZER,
+          "mixed", "none")
 
 _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 # fused instructions that compute nothing of their own: XLA shares and
@@ -370,11 +372,14 @@ _BUCKET_RE = re.compile(re.escape(BUCKET) + r"\d+")
 
 
 def scope_layer(op_name: str) -> str:
-    """The layer an ``op_name`` records: the outermost program scope in it.
-    Under ``model``, an op that ``value_and_grad`` transposed is backward
-    (a forward recomputed inside the backward included), any other forward.
-    No program scope: ``none``."""
-    for part in op_name.split("/"):
+    """The layer an ``op_name`` records: ``embed.grad`` wherever it appears,
+    else the outermost program scope in it.  Under ``model``, an op that
+    ``value_and_grad`` transposed is backward (a forward recomputed inside
+    the backward included), any other forward.  No program scope: ``none``."""
+    parts = op_name.split("/")
+    if EMBED_GRAD in parts:
+        return EMBED_GRAD
+    for part in parts:
         name = part.rstrip(")").rsplit("(", 1)[-1]   # transpose(jvp(model)) -> model
         if name == MODEL:
             return "backward" if part.startswith("transpose(") else "forward"

@@ -121,27 +121,28 @@ def _dense_attention(q, k, v, causal):
                       precision="highest")
 
 
-@pytest.mark.parametrize("B,Hq,Hkv,Sq,Skv,causal,dtype,tol", [
-    (2, 2, 2, 200, 300, False, jnp.float32, 1e-4),   # cross-attention shape
-    (1, 2, 2, 300, 300, False, jnp.float32, 1e-4),
-    (1, 2, 2, 200, 200, True, jnp.float32, 1e-4),
-    (1, 2, 2, 256, 256, True, jnp.float32, 1e-4),    # aligned: no padding
-    (1, 4, 2, 200, 200, True, jnp.float32, 1e-4),    # GQA
-    (1, 1, 1, 100, 600, False, jnp.float32, 1e-4),   # one kv block, 5 tiles
-    (1, 1, 1, 100, 2200, False, jnp.float32, 1e-4),  # 9 kv blocks of 256
-    (1, 1, 1, 200, 300, False, jnp.bfloat16, 3e-2),
+@pytest.mark.parametrize("B,Hq,Hkv,Sq,Skv,d,causal,dtype,tol", [
+    (2, 2, 2, 200, 300, 64, False, jnp.float32, 1e-4),   # cross-attention shape
+    (1, 2, 2, 300, 300, 64, False, jnp.float32, 1e-4),
+    (1, 2, 2, 200, 200, 64, True, jnp.float32, 1e-4),
+    (1, 2, 2, 256, 256, 64, True, jnp.float32, 1e-4),    # aligned: no padding
+    (1, 4, 2, 200, 200, 64, True, jnp.float32, 1e-4),    # GQA
+    (1, 1, 1, 100, 600, 64, False, jnp.float32, 1e-4),   # one kv block, 5 tiles
+    (1, 1, 1, 100, 2200, 64, False, jnp.float32, 1e-4),  # 9 kv blocks of 256
+    (1, 1, 1, 200, 300, 64, False, jnp.bfloat16, 3e-2),
+    (1, 1, 1, 3072, 3072, 80, True, jnp.float32, 1e-4),  # 3 q, kv blocks of 1024
 ], ids=["cross", "self", "causal", "aligned", "gqa", "kv-tiles", "kv-blocks",
-        "bf16"])
-def test_flash_kernel_padded_matches_softmax(B, Hq, Hkv, Sq, Skv, causal,
+        "bf16", "causal-long-hd80"])
+def test_flash_kernel_padded_matches_softmax(B, Hq, Hkv, Sq, Skv, d, causal,
                                              dtype, tol):
     """The padded kernel path's output and dq, dk, dv against a dense f32
     softmax (tolerances relative to each array's largest entry)."""
     from repro.models.attention import _flash_pallas
     ks = jax.random.split(jax.random.key(Sq * 7 + Skv + Hq), 4)
-    q = jax.random.normal(ks[0], (B, Hq, Sq, 64)).astype(dtype)
-    k = jax.random.normal(ks[1], (B, Hkv, Skv, 64)).astype(dtype)
-    v = jax.random.normal(ks[2], (B, Hkv, Skv, 64)).astype(dtype)
-    do = jax.random.normal(ks[3], (B, Hq, Sq, 64))
+    q = jax.random.normal(ks[0], (B, Hq, Sq, d)).astype(dtype)
+    k = jax.random.normal(ks[1], (B, Hkv, Skv, d)).astype(dtype)
+    v = jax.random.normal(ks[2], (B, Hkv, Skv, d)).astype(dtype)
+    do = jax.random.normal(ks[3], (B, Hq, Sq, d))
     f32 = lambda x: x.astype(jnp.float32)
     out, vjp = jax.vjp(lambda *a: _flash_pallas(*a, causal), q, k, v)
     ref, vjp_ref = jax.vjp(lambda *a: _dense_attention(*a, causal),

@@ -2,10 +2,11 @@
 checked without one.
 
 Each Pallas kernel of the explicit gradient exchange is compiled at a 64 MiB
-fusion bucket, and whisper-base's three attentions (forward and backward) at
-the benchmark's shapes, for one chip of a described (not attached)
-``v5e:2x2`` topology, and must come out as Mosaic ``tpu_custom_call``s: what
-interpret mode on the CPU cannot show (tiling, VMEM limits, lowering).
+fusion bucket, and the attentions (forward and backward) of whisper-base and
+StableLM-3B-4E1T at the benchmark's shapes, for one chip of a described (not
+attached) ``v5e:2x2`` topology, and must come out as Mosaic
+``tpu_custom_call``s: what interpret mode on the CPU cannot show (tiling,
+VMEM limits, lowering).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and the test workers all import
@@ -84,17 +85,21 @@ def test_fused_add_compiles_for_v5e(one_chip, dtype):
     assert 'custom_call_target="tpu_custom_call"' in text
 
 
-@pytest.mark.parametrize("Sq,Skv,causal", [(1500, 1500, False),
-                                           (448, 1500, False),
-                                           (448, 448, True)],
-                         ids=["encoder", "cross", "decoder"])
-def test_attention_kernels_compile_for_v5e(one_chip, monkeypatch, Sq, Skv,
-                                           causal):
-    """32 x 8 heads x 64, bf16: the forward kernel and the fused backward
-    kernel, and no f32 score matrix left in the compiled gradient."""
+@pytest.mark.parametrize("B,H,Sq,Skv,d,causal", [
+    (32, 8, 1500, 1500, 64, False),
+    (32, 8, 448, 1500, 64, False),
+    (32, 8, 448, 448, 64, True),
+    (1, 32, 4096, 4096, 80, True),
+], ids=["encoder", "cross", "decoder", "stablelm"])
+def test_attention_kernels_compile_for_v5e(one_chip, monkeypatch, B, H, Sq,
+                                           Skv, d, causal):
+    """whisper-base's three attentions (32 x 8 heads x 64) and
+    StableLM-3B-4E1T's (1 x 32 heads x 80, causal at 4096), bf16: the
+    forward kernel and the fused backward kernel, and no f32 score matrix
+    left in the compiled gradient."""
     # the described chip is not the default backend: compile, not interpret
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    shape = lambda s: jax.ShapeDtypeStruct((32, 8, s, 64), jnp.bfloat16,
+    shape = lambda s: jax.ShapeDtypeStruct((B, H, s, d), jnp.bfloat16,
                                            sharding=one_chip)
 
     def loss(q, k, v):
@@ -104,4 +109,4 @@ def test_attention_kernels_compile_for_v5e(one_chip, monkeypatch, Sq, Skv,
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), shape(Sq),
                           shape(Skv), shape(Skv))
     assert text.count('custom_call_target="tpu_custom_call"') == 2
-    assert f"f32[32,8,{Sq},{Skv}]" not in text
+    assert f"f32[{B},{H},{Sq},{Skv}]" not in text

@@ -103,6 +103,10 @@ def _without_metadata(text):
     ("jit(train_step)/shard_map/exchange.unpack/convert_element_type",
      "exchange.unpack"),
     ("jit(train_step)/optimizer/sqrt", "optimizer"),
+    ("jit(train_step)/transpose(jvp(model))/embed.grad/scatter-add",
+     "embed.grad"),
+    ("jit(train_step)/shard_map/transpose(jvp(model))/embed.grad/"
+     "convert_element_type", "embed.grad"),
     ("jit(train_step)/shard_map/psum", "none"),
 ])
 def test_scope_layer(op_name, layer):
@@ -111,10 +115,11 @@ def test_scope_layer(op_name, layer):
 
 @pytest.mark.parametrize("mode", MODES)
 def test_every_layer_of_the_step_has_instructions(compiled, mode):
-    """Forward, backward and optimizer in both modes; the exchange's pack,
-    buckets and unpack where the program runs them, in explicit mode."""
+    """Forward, backward, the embedding gradient and optimizer in both
+    modes; the exchange's pack, buckets and unpack where the program runs
+    them, in explicit mode."""
     got = set(hlo.layers(compiled[mode]["scoped"]).values())
-    assert {"forward", "backward", "optimizer"} <= got
+    assert {"forward", "backward", hlo.EMBED_GRAD, "optimizer"} <= got
     exchange = {hlo.PACK, hlo.BUCKET, hlo.UNPACK}
     if mode == "explicit":
         assert exchange <= got
