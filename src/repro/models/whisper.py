@@ -71,7 +71,16 @@ def init_model(key, cfg: ModelConfig) -> Params:
 # ---------------------------------------------------------------------------
 
 def encode(params: Params, frames: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
-    """frames: (B, enc_seq, D) stub embeddings -> encoder hidden states."""
+    """frames: (B, enc_seq, D) stub embeddings -> encoder hidden states.
+
+    Frames of any float dtype are cast to ``cfg.dtype`` first, as
+    ``transformer.forward`` casts ``prefix_embeds``: the stub stands in for
+    the conv frontend, whose activations are already in the model's compute
+    dtype. Uncast f32 frames would promote every encoder layer, and the
+    cross-attention k/v built from its output, to f32.
+    """
+    frames = frames.astype(cfg.dtype)
+
     def body(x, bp):
         h = rms_norm(x, bp["ln1"]["w"], cfg.norm_eps)
         B, S, D = h.shape

@@ -197,6 +197,78 @@ def test_pallas_dispatch_in_whisper():
                                    rtol=2e-4, atol=2e-4)
 
 
+def _weight_dots(jaxpr, weights):
+    """Yield every dot_general in ``jaxpr`` (sub-jaxprs included) with an
+    operand derived from a var in ``weights``, by casts, reshapes, slices
+    and transposes, through scan and remat bodies."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr, Var
+    follow = {"convert_element_type", "reshape", "transpose", "squeeze",
+              "broadcast_in_dim", "slice", "dynamic_slice", "copy"}
+    for eqn in jaxpr.eqns:
+        hit = [isinstance(v, Var) and v in weights for v in eqn.invars]
+        if eqn.primitive.name == "dot_general" and any(hit):
+            yield eqn
+        if eqn.primitive.name in follow and any(hit):
+            weights.update(eqn.outvars)
+        for sub in eqn.params.values():
+            sub = sub.jaxpr if isinstance(sub, ClosedJaxpr) else sub
+            if isinstance(sub, Jaxpr) and len(sub.invars) == len(eqn.invars):
+                yield from _weight_dots(
+                    sub, {v for v, h in zip(sub.invars, hit) if h})
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_whisper_encoder_runs_in_config_dtype(dtype, monkeypatch):
+    """f32 frames from the stub frontend enter whisper's encoder in
+    cfg.dtype: its output, the q/k/v reaching attention and every matmul
+    against a weight are in that dtype, so a bfloat16 model's encoder does
+    not promote to float32."""
+    from repro.configs import get_config
+    from repro.models import whisper as w
+    cfg = get_config("whisper-base").smoke().replace(dtype=dtype)
+    want = jnp.dtype(dtype)
+    params = w.init_model(jax.random.key(0), cfg)
+    frames = jax.random.normal(jax.random.key(1),
+                               (2, cfg.encoder_seq, cfg.d_model), jnp.float32)
+    seen, real = [], w.flash_attention
+
+    def recording(q, k, v, **kw):
+        seen.append((q.dtype, k.dtype, v.dtype))
+        return real(q, k, v, **kw)
+
+    monkeypatch.setattr(w, "flash_attention", recording)
+    out = w.encode(params, frames, cfg)
+    assert out.dtype == want
+    assert seen and all(d == (want,) * 3 for d in seen), seen
+
+    closed = jax.make_jaxpr(lambda p, f: w.encode(p, f, cfg))(params, frames)
+    n_weights = len(jax.tree_util.tree_leaves(params))
+    dots = list(_weight_dots(closed.jaxpr,
+                             set(closed.jaxpr.invars[:n_weights])))
+    assert len(dots) == 7                               # q, k, v, o, 3 mlp
+    for eqn in dots:
+        assert [v.aval.dtype for v in eqn.invars] == [want, want], eqn
+
+
+def test_whisper_prefill_cross_cache_matches_cache_spec():
+    """prefill's cross-attention cache (xk, xv), built from f32 frames, has
+    the dtype and shape cache_spec declares."""
+    from repro.configs import get_config
+    from repro.models import whisper as w
+    cfg = get_config("whisper-base").smoke().replace(dtype="bfloat16")
+    params = w.init_model(jax.random.key(0), cfg)
+    B, S = 2, 8
+    frames = jax.random.normal(jax.random.key(1),
+                               (B, cfg.encoder_seq, cfg.d_model), jnp.float32)
+    tokens = jax.random.randint(jax.random.key(2), (B, S), 0, cfg.vocab_size)
+    _, caches = jax.eval_shape(lambda p, t, f: w.prefill(p, t, f, cfg),
+                               params, tokens, frames)
+    spec = w.cache_spec(cfg, B, S)
+    for name in ("xk", "xv"):
+        shape, dtype = spec[name]
+        assert (caches[name].shape, caches[name].dtype) == (shape, dtype), name
+
+
 def test_pallas_dispatch_mamba():
     """use_pallas routes the mamba scan through kernels/ssm_scan with
     matching forward and (reference-backward) gradients."""
