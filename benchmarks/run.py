@@ -5,7 +5,6 @@ Sections:
      checks against the paper's own numbers.
   2. Kernel micro-benchmarks (Pallas interpret-mode vs jnp ref).
   3. TPU what-if for the assigned architectures (beyond-paper).
-  4. Roofline table from the dry-run artifacts, if present.
 
 Prints ``name,us_per_call,derived`` CSV per benchmark plus a validation
 summary; exits non-zero if a paper-claim check fails.
@@ -132,30 +131,6 @@ def main() -> int:
             print(f"tpu_whatif[{arch},pods={n_pods}],0,"
                   f"f_sim={r.scaling_factor:.3f};overhead_ms="
                   f"{r.t_overhead*1e3:.2f}")
-
-    # -- 4. roofline ----------------------------------------------------------
-    print("\n" + "=" * 72)
-    print("SECTION 4: roofline from dry-run artifacts")
-    print("=" * 72)
-    art = Path(__file__).resolve().parent.parent / "artifacts" / "dryrun"
-    from benchmarks.roofline import load_table
-    for fname in ("results.json", "results_multipod.json"):
-        path = art / fname
-        if not path.exists():
-            print(f"({fname} not present — run repro.launch.dryrun first)")
-            continue
-        rows = load_table(path)
-        print(f"\n-- {fname}: {len(rows)} combos --")
-        print("name,us_per_call,derived")
-        for r in rows:
-            if r.get("kind") == "skipped":
-                print(f"roofline[{r['arch']},{r['shape']}],0,skipped")
-                continue
-            print(f"roofline[{r['arch']},{r['shape']},{r['mesh']}],0,"
-                  f"dominant={r['dominant']};compute_ms={r['compute_s']*1e3:.2f};"
-                  f"memory_ms={r['memory_s']*1e3:.2f};"
-                  f"collective_ms={r['collective_s']*1e3:.2f};"
-                  f"useful_ratio={r['model_flops_ratio']:.2f}")
 
     print(f"\n{'ALL BENCHMARKS PASS' if failures == 0 else f'{failures} FAILURES'}")
     return failures

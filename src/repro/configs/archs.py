@@ -12,7 +12,7 @@ ALL_ARCHS = ["jamba-v0.1-52b", "command-r-35b", "rwkv6-1.6b", "internvl2-2b",
              "deepseek-coder-33b", "moonshot-v1-16b-a3b"]
 
 # archs whose attention is full/quadratic: long_500k runs the sliding-window
-# variant (see DESIGN.md §Arch-applicability); whisper skips long_500k.
+# variant; whisper skips long_500k.
 FULL_ATTENTION = ["command-r-35b", "internvl2-2b", "stablelm-3b",
                   "deepseek-v2-236b", "arctic-480b", "deepseek-coder-33b",
                   "moonshot-v1-16b-a3b"]

@@ -75,14 +75,8 @@ class ModelConfig:
     # --- distribution ---
     sharding: str = "dp_tp"          # dp_tp | fsdp_tp
     remat: bool = True               # activation checkpointing per layer
-    # --- §Perf hillclimb knobs (defaults = paper-faithful baseline) ---
+    # --- kernels and rematerialization ---
     mamba_fused_y: bool = False      # contract d_state inside the chunk scan
-    moe_shard: str = "edim_dmodel"   # edim_dmodel (baseline) | edim_dff
-    fsdp_unshard_step: bool = False  # ZeRO-1: all-gather params once per step
-    bf16_stream: bool = False        # keep residual/collective tensors bf16
-    mamba_scan_impl: str = "assoc"   # assoc (log-depth) | seq (VMEM-carry)
-    seq_parallel: str = ""           # batch axes, e.g. "data": shard the
-                                     # residual stream's S dim over `model`
     remat_policy: str = "full"       # full | dots (save matmul outputs)
     use_pallas: str = "auto"         # auto (TPU only) | always | never
     # --- provenance ---
@@ -164,20 +158,6 @@ INPUT_SHAPES = {
     "decode_32k": InputShape("decode_32k", 32768, 128, "decode"),
     "long_500k": InputShape("long_500k", 524288, 1, "decode"),
 }
-
-
-def production_overrides(cfg: "ModelConfig") -> dict:
-    """The §Perf-validated beyond-paper flags per architecture family
-    (EXPERIMENTS.md §Perf).  Baselines keep defaults; the optimized
-    dry-run sweep (`dryrun --production`) and deployments apply these."""
-    kw: dict = {"attn_chunk": 2048}
-    if cfg.sharding == "fsdp_tp":
-        kw["fsdp_unshard_step"] = True
-    if cfg.ssm is not None and cfg.ssm.kind == "mamba":
-        kw["mamba_fused_y"] = True
-    if cfg.moe is not None:
-        kw["moe_shard"] = "edim_dff"
-    return kw
 
 
 @dataclass(frozen=True)

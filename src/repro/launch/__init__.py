@@ -1,1 +1,1 @@
-"""Launchers: production meshes, multi-pod dry-run, train, serve."""
+"""Launchers: train (data-parallel training) and serve (decode)."""

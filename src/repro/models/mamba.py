@@ -86,7 +86,7 @@ def _ssm_scan_chunked(decay, bx, h0, chunk: int):
 
 
 def _ssm_scan_chunked_fused_y(decay, bx, c_t, h0, chunk: int):
-    """§Perf variant (``cfg.mamba_fused_y``): contract the d_state axis
+    """Variant (``cfg.mamba_fused_y``): contract the d_state axis
     against C inside the chunk step, so the scan emits y chunks
     (B, C, di) instead of state chunks (B, C, di, n) — an n-fold (16x)
     reduction of the scan's stacked output and its backward residual.
@@ -115,28 +115,6 @@ def _ssm_scan_chunked_fused_y(decay, bx, c_t, h0, chunk: int):
     h_final, yc = jax.lax.scan(step, h0, (dc, bc, cc))
     y = yc.transpose(1, 0, 2, 3).reshape(B, S, di)
     return y, h_final
-
-
-def _ssm_scan_seq_fused_y(decay, bx, c_t, h0):
-    """§Perf variant (``mamba_scan_impl="seq"`` + fused y): one sequential
-    ``lax.scan`` over time with the (B, di, n) state carried in
-    VMEM/registers.  ~3 HBM passes over (B, S, di, n) (read decay, read bx,
-    write y/di-only) vs ~2*log2(C) for the associative scan's pad/slice
-    cascade.  The Pallas deployment kernel (repro.kernels.ssm_scan) is the
-    same dataflow with explicit VMEM tiling.
-
-    Returns (y (B, S, di), h_final).
-    """
-    def step(h, inp):
-        d, b, ct = inp                        # (B, di, n), (B, di, n), (B, n)
-        h = d * h + b
-        y = jnp.einsum("bdn,bn->bd", h, ct)
-        return h, y
-
-    h_final, ys = jax.lax.scan(
-        step, h0, (decay.transpose(1, 0, 2, 3), bx.transpose(1, 0, 2, 3),
-                   c_t.transpose(1, 0, 2)))
-    return ys.transpose(1, 0, 2), h_final
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -207,25 +185,18 @@ def mamba_mixer(params: Params, x: jnp.ndarray, cfg: ModelConfig,
     bx = (dt * xs.astype(jnp.float32))[..., None] * b_t[:, :, None, :]
     h0 = (state["ssm"] if state is not None
           else jnp.zeros((B, di, n), jnp.float32))
-    if cfg.bf16_stream:
-        # §Perf: halve the scan's HBM traffic; decays are products of
-        # values <= 1 (bf16-safe) and bx accumulates over one chunk only
-        decay, bx, c_t = (t.astype(jnp.bfloat16) for t in (decay, bx, c_t))
-        h0 = h0.astype(jnp.bfloat16)
     if _use_pallas_scan(cfg, S, di):
         y, h_final = _ssm_pallas_cv(decay, bx, c_t, h0, cfg.ssm.chunk_size)
-    elif cfg.mamba_scan_impl == "seq":
-        y, h_final = _ssm_scan_seq_fused_y(decay, bx, c_t, h0)
     elif cfg.mamba_fused_y:
         y, h_final = _ssm_scan_chunked_fused_y(decay, bx, c_t, h0,
                                                cfg.ssm.chunk_size)
     else:
         states, h_final = _ssm_scan_chunked(decay, bx, h0, cfg.ssm.chunk_size)
         y = jnp.einsum("bsdn,bsn->bsd", states, c_t)
-    y = y.astype(jnp.float32) + params["d_skip"] * xs.astype(jnp.float32)
+    y = y + params["d_skip"] * xs.astype(jnp.float32)
     y = (y.astype(x.dtype)) * jax.nn.silu(z)
     out = y @ params["w_out"]
-    return out, {"conv": conv_state, "ssm": h_final.astype(jnp.float32)}
+    return out, {"conv": conv_state, "ssm": h_final}
 
 
 def mamba_decode(params: Params, x: jnp.ndarray, state: Dict[str, jnp.ndarray],

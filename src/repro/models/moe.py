@@ -7,9 +7,8 @@ TPU-native choices:
   through the residual connection),
 - router computed in fp32; load-balance + router-z auxiliary losses.
 
-The gather/scatter ("sort-based") dispatch is intentionally NOT the baseline:
-the einsum form is what the roofline baseline measures, and replacing it is
-one of the §Perf hillclimb candidates.
+There is no gather/scatter ("sort-based") dispatch and no expert-parallel
+all-to-all: the einsum form is the only one.
 """
 from __future__ import annotations
 
@@ -78,13 +77,10 @@ def moe_block(params: Params, x: jnp.ndarray, cfg: ModelConfig
     pos_idx = jnp.sum(pos * sel, axis=-1).astype(jnp.int32)      # (B,S,K)
 
     # dispatch/combine tensors (B,S,E,C) -----------------------------------
-    # (bf16_stream: one-hots exact in bf16; gate rounding <0.4% — halves the
-    # largest MoE intermediates' HBM traffic)
-    oh_dt = jnp.bfloat16 if getattr(cfg, "bf16_stream", False) else jnp.float32
-    pos_oh = jax.nn.one_hot(pos_idx, C, dtype=oh_dt)             # (B,S,K,C)
-    disp = jnp.einsum("bske,bskc->bsec", sel.astype(oh_dt), pos_oh)
-    comb = jnp.einsum("bske,bskc,bsk->bsec", sel.astype(oh_dt), pos_oh,
-                      gate_vals.astype(oh_dt))
+    pos_oh = jax.nn.one_hot(pos_idx, C, dtype=jnp.float32)       # (B,S,K,C)
+    disp = jnp.einsum("bske,bskc->bsec", sel, pos_oh)
+    comb = jnp.einsum("bske,bskc,bsk->bsec", sel, pos_oh,
+                      gate_vals.astype(jnp.float32))
 
     dt = x.dtype
     xin = jnp.einsum("bsec,bsd->ebcd", disp.astype(dt), x)       # (E,B,C,D)

@@ -12,7 +12,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn_lib
@@ -98,23 +97,12 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
     aux_total = jnp.zeros((), jnp.float32)
     caches = {}
 
-    # sequence parallelism (§Perf): keep the residual stream sharded
-    # (batch over data, S over model) between layers so norms/MLP run on
-    # S-shards and the remat residual is saved sharded — kills the
-    # full-D all-gathers in backward.
-    sp_axes = tuple(a for a in cfg.seq_parallel.split(",") if a)
-    sp_spec = P(sp_axes if sp_axes else None, "model", None)
-
     def run_stack(x, stack, n_layers, use_moe, name):
         nonlocal aux_total, caches
 
         def body(carry, lp):
             h, aux_acc = carry
-            if cfg.seq_parallel:
-                h = jax.lax.with_sharding_constraint(h, sp_spec)
             h, aux, cache = _block_forward(lp, h, cfg, use_moe)
-            if cfg.seq_parallel:
-                h = jax.lax.with_sharding_constraint(h, sp_spec)
             aux_acc = aux_acc + sum(aux.values()) if aux else aux_acc
             out = cache if want_cache else None
             return (h, aux_acc), out

@@ -1,7 +1,7 @@
 """Optimizers in pure JAX (no optax dependency): SGD, momentum, AdamW.
 
-State layout mirrors the param pytree so the ZeRO-1 sharding rules in
-``repro.parallel.sharding`` apply leaf-by-leaf.
+State layout mirrors the param pytree.  The trainer
+(``repro.launch.train``) replicates it on every device, like the params.
 """
 from __future__ import annotations
 

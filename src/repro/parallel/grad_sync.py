@@ -239,15 +239,3 @@ def sync_grads(grads: Any, comm: CommConfig,
             prev = synced[b] = _sync_bucket(x, comm, axes)
     with jax.named_scope(hlo.UNPACK):
         return jax.tree_util.tree_unflatten(treedef, unpack(plan, synced))
-
-
-def grad_sync_flops_and_bytes(total_bytes: int, n_workers: int,
-                              comm: CommConfig) -> dict:
-    """Analytic wire traffic of one sync — feeds the simulator/benchmarks."""
-    ratio = {"none": 1.0, "fp16": 2.0, "int8": 4.0, "ternary": 4.0,
-             "topk": 1.0 / max(comm.topk_ratio, 1e-9) / 2.0}[comm.compression]
-    if comm.compression == "none":
-        wire = 2.0 * total_bytes * (n_workers - 1) / n_workers
-    else:  # all-gather of compressed payloads
-        wire = total_bytes / ratio * (n_workers - 1)
-    return {"wire_bytes_per_worker": wire, "compression_ratio": ratio}

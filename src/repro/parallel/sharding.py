@@ -1,15 +1,17 @@
 """Sharding rules: map every parameter / activation / cache leaf to a
-PartitionSpec for the production meshes.
+PartitionSpec for a (pod, data, model) mesh.
 
 Strategies
 ----------
 ``dp_tp``   batch over (pod, data); tensor-parallel over `model`;
             params replicated across `data`.
-``fsdp_tp`` as above, plus parameters and optimizer state sharded over
-            `data` *within* a pod (hybrid FSDP: replicated across pods so
-            param all-gathers stay on ICI, gradients cross DCN once).
+``fsdp_tp`` as above, plus parameters sharded over `data` *within* a pod
+            (hybrid FSDP: replicated across pods so param all-gathers stay
+            on ICI, gradients cross DCN once).
 
-Optimizer state is always ZeRO-1 sharded (see repro/optim).
+A rule table only: the trainer (``repro.launch.train``) runs on a 1-D
+``data`` mesh with parameters and optimizer state replicated, and applies
+none of these specs yet.
 """
 from __future__ import annotations
 
@@ -103,29 +105,7 @@ _RULES: Dict[Tuple[str, str], Tuple[Optional[str], ...]] = {
     ("rwkv", "wr_ch"): ("f", None),
 }
 
-# §Perf variant (cfg.moe_shard == "edim_dff"): keep experts on `model` but
-# move the fsdp axis off the CONTRACTING d_model dim onto d_ff, so matmuls
-# never contract a sharded dim — XLA stops all-gathering expert weights and
-# instead all-reduces the (small) activations.  Same storage footprint.
-_MOE_DFF_RULES: Dict[Tuple[str, str], Tuple[Optional[str], ...]] = {
-    ("moe", "wi"): ("m", None, "f"),
-    ("moe", "wg"): ("m", None, "f"),
-    ("moe", "wo"): ("m", "f", None),
-}
-
-# §Perf variant "dff_only" (dp_tp MoE, e.g. moonshot): replicate the expert
-# dim and TP-shard d_ff — the dispatch/combine einsums see no sharded E, so
-# their backward stops all-gathering (E,B,C,D); the wo partial sums defer
-# through the combine to a (B,S,D)-sized all-reduce.
-_MOE_DFF_ONLY_RULES: Dict[Tuple[str, str], Tuple[Optional[str], ...]] = {
-    ("moe", "wi"): (None, None, "m"),
-    ("moe", "wg"): (None, None, "m"),
-    ("moe", "wo"): (None, "m", None),
-}
-
-
-def _leaf_spec(path: Tuple[str, ...], ndim: int, strategy: str,
-               moe_shard: str = "edim_dmodel") -> P:
+def _leaf_spec(path: Tuple[str, ...], ndim: int, strategy: str) -> P:
     f = "data" if strategy == "fsdp_tp" else None
     key = None
     for i in range(len(path) - 1):
@@ -135,13 +115,8 @@ def _leaf_spec(path: Tuple[str, ...], ndim: int, strategy: str,
         key = (path[-2], path[-1])
     if key is None:
         return P()  # norms, biases, scalars: replicated
-    roles = _RULES[key]
-    if moe_shard == "edim_dff" and key in _MOE_DFF_RULES:
-        roles = _MOE_DFF_RULES[key]
-    elif moe_shard == "dff_only" and key in _MOE_DFF_ONLY_RULES:
-        roles = _MOE_DFF_ONLY_RULES[key]
     spec = tuple({"f": f, "m": "model"}.get(r, None) if isinstance(r, str) else None
-                 for r in roles)
+                 for r in _RULES[key])
     if len(spec) > ndim:       # un-stacked single layer params
         spec = spec[-ndim:]
     pad = (None,) * (ndim - len(spec))
@@ -160,10 +135,8 @@ def _path_names(path) -> Tuple[str, ...]:
 
 def param_specs(params_shape: Any, cfg: ModelConfig) -> Any:
     """Pytree of PartitionSpec matching a pytree of ShapeDtypeStruct/arrays."""
-    moe_shard = getattr(cfg, "moe_shard", "edim_dmodel")
     def one(path, leaf):
-        return _leaf_spec(_path_names(path), len(leaf.shape), cfg.sharding,
-                          moe_shard)
+        return _leaf_spec(_path_names(path), len(leaf.shape), cfg.sharding)
     return jax.tree_util.tree_map_with_path(one, params_shape)
 
 

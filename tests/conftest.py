@@ -1,7 +1,6 @@
 # NOTE: no XLA_FLAGS here on purpose — smoke tests and benches must see the
-# single real CPU device.  Only repro/launch/dryrun.py (and the dedicated
-# multi-device subprocess tests) force a fake device count, in their own
-# processes.
+# single real CPU device.  Only the dedicated multi-device subprocess tests
+# force a fake device count, in their own processes.
 import sys
 from pathlib import Path
 
