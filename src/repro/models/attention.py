@@ -22,6 +22,9 @@ from repro.configs.base import ModelConfig
 from repro.models.layers import Params, apply_rope, dense_init, split_keys
 
 NEG_INF = -1e30
+# The splash kernel names its residuals (``out``, ``logsumexp``) so that a
+# checkpoint policy can keep them (``remat``); elsewhere the name does nothing.
+SPLASH_RESIDUALS = "attn.splash_residuals"
 
 
 # ---------------------------------------------------------------------------
@@ -92,6 +95,25 @@ def _tile(n: int, largest: int = 512) -> int:
     return next(b for b in (1024, 512, 256, 128) if b <= largest and n % b == 0)
 
 
+def remat(body, cfg):
+    """``body`` under ``jax.checkpoint`` when ``cfg.remat``, else ``body``.
+
+    The policy keeps the splash kernel's residuals, so the backward reads the
+    ``out`` and ``logsumexp`` the forward wrote and does not run the attention
+    forward again; everything else is recomputed (``remat_policy="full"``) or,
+    under ``"dots"``, matmul outputs without batch dimensions are kept too. A
+    layer with no splash call saves what a plain checkpoint saves.
+    """
+    if not cfg.remat:
+        return body
+    cp = jax.checkpoint_policies
+    policy = cp.save_only_these_names(SPLASH_RESIDUALS)
+    if cfg.remat_policy == "dots":
+        policy = cp.save_from_both_policies(
+            cp.dots_with_no_batch_dims_saveable, policy)
+    return jax.checkpoint(body, policy=policy)
+
+
 def _flash_pallas(q, k, v, causal):
     """JAX's Pallas TPU splash-attention kernel: forward, and one fused
     backward kernel for dq, dk and dv, with f32 softmax statistics; the
@@ -131,7 +153,8 @@ def _flash_pallas(q, k, v, causal):
         v_layout=seq_minor)
     kernel = splash.make_splash_mha(
         splash.MultiHeadMask([mask] * Hq), block_sizes=blocks, head_shards=1,
-        q_seq_shards=1, interpret=jax.default_backend() != "tpu")
+        q_seq_shards=1, residual_checkpoint_name=SPLASH_RESIDUALS,
+        interpret=jax.default_backend() != "tpu")
     pad = lambda x, n: jnp.pad(x, ((0, 0), (0, 0), (0, n - x.shape[2]), (0, 0)))
     q = (q * (1.0 / math.sqrt(d))).astype(q.dtype)   # the kernel does not scale
     out = jax.vmap(kernel)(pad(q, Sqp), pad(k, Skp), pad(v, Skp))

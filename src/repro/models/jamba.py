@@ -113,9 +113,9 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
                 caches[f"l{i}"] = cache
         return (h, aux_acc), (caches if want_cache else None)
 
-    body_fn = jax.checkpoint(body) if cfg.remat else body
-    (x, aux), caches = jax.lax.scan(
-        body_fn, (x, jnp.zeros((), jnp.float32)), params["blocks"])
+    (x, aux), caches = jax.lax.scan(attn_lib.remat(body, cfg),
+                                    (x, jnp.zeros((), jnp.float32)),
+                                    params["blocks"])
     x = rms_norm(x, params["final_norm"]["w"], cfg.norm_eps)
     return x, aux, caches
 

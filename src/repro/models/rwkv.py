@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.models.attention import remat
 from repro.models.layers import (Params, chunked_softmax_xent, dense_init,
                                  embed_init, embed_lookup, rms_norm,
                                  split_keys)
@@ -244,7 +245,7 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
         h, new_state = _block(lp, h, cfg, lst)
         return h, (new_state if want_state else None)
 
-    body_fn = jax.checkpoint(body) if cfg.remat else body
+    body_fn = remat(body, cfg)
     if state is None:
         B = tokens.shape[0]
         H, hd = head_dims(cfg)

@@ -107,13 +107,7 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
             out = cache if want_cache else None
             return (h, aux_acc), out
 
-        if cfg.remat and cfg.remat_policy == "dots":
-            body_fn = jax.checkpoint(
-                body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-        elif cfg.remat:
-            body_fn = jax.checkpoint(body)
-        else:
-            body_fn = body
+        body_fn = attn_lib.remat(body, cfg)
         if cfg.scan_layers and n_layers > 1:
             (x, aux_acc), cache = jax.lax.scan(body_fn, (x, jnp.zeros((), jnp.float32)), stack)
         else:

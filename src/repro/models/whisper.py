@@ -95,8 +95,7 @@ def encode(params: Params, frames: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray
         x = x + mlp(bp["mlp"], rms_norm(x, bp["ln2"]["w"], cfg.norm_eps))
         return x, None
 
-    body_fn = jax.checkpoint(body) if cfg.remat else body
-    x, _ = jax.lax.scan(body_fn, frames, params["enc_blocks"])
+    x, _ = jax.lax.scan(attn_lib.remat(body, cfg), frames, params["enc_blocks"])
     return rms_norm(x, params["enc_norm"]["w"], cfg.norm_eps)
 
 
@@ -143,7 +142,7 @@ def decode_stack(params: Params, tokens: jnp.ndarray, enc_out: jnp.ndarray,
         h, cache = _dec_block(bp, h, enc_out, cfg, want_cache)
         return h, cache
 
-    body_fn = jax.checkpoint(body) if (cfg.remat and not want_cache) else body
+    body_fn = body if want_cache else attn_lib.remat(body, cfg)
     x, caches = jax.lax.scan(body_fn, x, params["dec_blocks"])
     return rms_norm(x, params["final_norm"]["w"], cfg.norm_eps), caches
 

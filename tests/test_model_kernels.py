@@ -311,3 +311,102 @@ def test_pallas_dispatch_rwkv():
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32),
                                    rtol=5e-2, atol=5e-3)
+
+
+def _smoke_loss(model, use_pallas, remat_policy):
+    """(loss(params), params) of a two-layer smoke model with remat on: the
+    transformer is StableLM's block (one causal attention a layer), whisper
+    has three (encoder, decoder and cross-attention)."""
+    from repro.configs import get_config
+    from repro.models import transformer as t
+    from repro.models import whisper as w
+    B, S = 2, 128
+    ks = jax.random.split(jax.random.key(1), 3)
+    tokens = {"tokens": jax.random.randint(ks[1], (B, S), 0, 512),
+              "labels": jax.random.randint(ks[2], (B, S), 0, 512)}
+    if model == "whisper":
+        cfg = get_config("whisper-base").smoke()
+        lib, init = w, w.init_model
+        batch = {"frames": jax.random.normal(
+            ks[0], (B, cfg.encoder_seq, cfg.d_model)) * 0.3, **tokens}
+    else:
+        cfg = get_config("stablelm-3b").smoke()
+        lib, init, batch = t, t.init_decoder, tokens
+    cfg = cfg.replace(remat=True, remat_policy=remat_policy,
+                      use_pallas=use_pallas)
+    params = init(jax.random.key(0), cfg)
+    return (lambda p: lib.loss_fn(p, batch, cfg)[0]), params
+
+
+def _plain_checkpoint(body, cfg):
+    """The reference for ``attention.remat``: a checkpoint that keeps no
+    named value (under ``dots``, matmul outputs only)."""
+    if cfg.remat_policy == "dots":
+        return jax.checkpoint(
+            body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+    return jax.checkpoint(body)
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and its sub-jaxprs, in order."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in eqn.params.values():
+            for s in sub if isinstance(sub, (tuple, list)) else (sub,):
+                s = s.jaxpr if isinstance(s, ClosedJaxpr) else s
+                if isinstance(s, Jaxpr):
+                    yield from _eqns(s)
+
+
+def _splash_forwards(closed):
+    return sum(eqn.primitive.name == "pallas_call"
+               and "splash_mha_fwd" in eqn.params["name"]
+               for eqn in _eqns(closed.jaxpr))
+
+
+def _grad_and_jaxpr(model, use_pallas, remat_policy, monkeypatch, plain):
+    from repro.models import attention as a
+    loss, params = _smoke_loss(model, use_pallas, remat_policy)
+    with monkeypatch.context() as m:
+        if plain:
+            m.setattr(a, "remat", _plain_checkpoint)
+        return (jax.jit(jax.grad(loss))(params),
+                jax.make_jaxpr(jax.grad(loss))(params))
+
+
+@pytest.mark.parametrize("remat_policy", ["full", "dots"])
+@pytest.mark.parametrize("model,sites", [("whisper", 3), ("transformer", 1)])
+def test_remat_keeps_splash_residuals(model, sites, remat_policy, monkeypatch):
+    """Under remat the backward reads the splash forward's saved out and
+    logsumexp: one forward kernel per attention site in the gradient's
+    jaxpr, against two under a plain checkpoint, with the same gradients."""
+    g, jaxpr = _grad_and_jaxpr(model, "always", remat_policy, monkeypatch,
+                               plain=False)
+    g_plain, jaxpr_plain = _grad_and_jaxpr(model, "always", remat_policy,
+                                           monkeypatch, plain=True)
+    assert _splash_forwards(jaxpr) == sites
+    assert _splash_forwards(jaxpr_plain) == 2 * sites
+    for x, y in zip(jax.tree_util.tree_leaves(g),
+                    jax.tree_util.tree_leaves(g_plain)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("remat_policy", ["full", "dots"])
+@pytest.mark.parametrize("model", ["whisper", "transformer"])
+def test_remat_without_kernel_is_plain_checkpoint(model, remat_policy,
+                                                  monkeypatch):
+    """On the jnp attention path nothing carries the residuals' name, so the
+    gradient's jaxpr under ``attention.remat`` holds the same equations as
+    under a plain checkpoint, and the gradients are the same."""
+    g, jaxpr = _grad_and_jaxpr(model, "never", remat_policy, monkeypatch,
+                               plain=False)
+    g_plain, jaxpr_plain = _grad_and_jaxpr(model, "never", remat_policy,
+                                           monkeypatch, plain=True)
+    sig = lambda j: [(e.primitive.name, [str(v.aval) for v in e.outvars])
+                     for e in _eqns(j.jaxpr)]
+    assert sig(jaxpr) == sig(jaxpr_plain)
+    assert _splash_forwards(jaxpr) == 0
+    for x, y in zip(jax.tree_util.tree_leaves(g),
+                    jax.tree_util.tree_leaves(g_plain)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
